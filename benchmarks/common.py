@@ -275,6 +275,12 @@ def expansion_bytes_model(Q: int, B: int, C: int, D: int,
     kernel the same way. Index-fused path: each kernel reads its rows once,
     straight from the resident corpus, in residency width — no staged
     intermediates. int8 adds 4 bytes/row of scale traffic.
+
+    Residency width is what the jnp-ref fused path reads. The Pallas fused
+    kernels on TPU gather from the store's kernel layout instead
+    (``CorpusStore.kernel_rows``): float32 rows padded to 128 lanes, so
+    each row read there costs ``4 * ceil(D / 128) * 128`` bytes whatever
+    the dtype — 512 B at D=40 or 64 (DESIGN.md §8).
     """
     s = _DTYPE_BYTES[corpus_dtype]
     scale = 4 if corpus_dtype == "int8" else 0
@@ -299,7 +305,11 @@ def grad_stage_bytes_model(Q: int, D: int, corpus_dtype: str = "float32",
     once, straight from the resident corpus, in residency width, plus ONE
     fp32 write of the dequantized rows it hands the rank stage (charged
     honestly — that write replaces the engine's whole gather+stage+read
-    cycle). int8 adds 4 bytes/row of scale traffic."""
+    cycle). int8 adds 4 bytes/row of scale traffic. As in
+    ``expansion_bytes_model``, the fused read is at residency width; the
+    TPU kernels read 512 B per row at D=40 from the lane-padded kernel
+    layout, which leaves fused and pre-gathered at about the same bytes
+    there (DESIGN.md §8)."""
     s = _DTYPE_BYTES[corpus_dtype]
     scale = 4 if corpus_dtype == "int8" else 0
     if fused:

@@ -106,17 +106,19 @@ def tile_occupancy_rows(Q: int = 128, B: int = 32, C: int = 8, D: int = 64,
     grid step costs whichever side is longer). Bt=1 is the pre-autotune
     rowwise grid — per-step overhead × M with nothing amortized."""
     kernels = {
-        # kernel -> (rows gathered per engine step, residency bytes/elem)
-        "neighbor_rank_fused": (Q * B, 4),
-        "deepfm_score_fused": (Q * C, 4),
-        "deepfm_grad_fused": (Q, 4),
+        # kernel -> rows gathered per engine step
+        "neighbor_rank_fused": Q * B,
+        "deepfm_score_fused": Q * C,
+        "deepfm_grad_fused": Q,
     }
+    # every row comes from the kernel layout: float32 padded to 128 lanes
+    row_bytes = 4 * -(-D // 128) * 128
     rows = []
-    for kern, (m, elem_bytes) in kernels.items():
+    for kern, m in kernels.items():
         t_row = None
         for bt in bts:
             steps = -(-m // bt)
-            tile_bytes = bt * D * elem_bytes
+            tile_bytes = bt * row_bytes
             vmem = 2 * tile_bytes            # double buffer
             t_model = steps * max(GRID_STEP_S, tile_bytes / HBM_BW)
             if bt == 1:

@@ -185,6 +185,9 @@ def main() -> None:
                             kernels_micro, residency, roofline, serving_load,
                             table2_breakdown)
     from repro.obs import profile_trace
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
 
     # Suites whose rows were produced with telemetry attached stamp that
     # into their BENCH provenance, so trajectory diffs never compare a

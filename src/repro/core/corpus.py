@@ -42,9 +42,17 @@ the whole corpus.
 bfloat16 payloads are held as their **uint16 bit patterns**: XLA:CPU's
 native bf16 gather scalarizes (measured *slower* than the fp32 gather it
 was meant to halve), while a u16 gather + integer widen + shift + bitcast
-is a pure-SIMD pipeline ~2.3x faster than the fp32 gather. On TPU the
-kernels bitcast u16→bf16 in VMEM for free, so one storage format serves
-both backends.
+is a pure-SIMD pipeline ~2.3x faster than the fp32 gather.
+
+**Kernel layout** (DESIGN.md §8): the Pallas index-fused kernels gather
+one row per DMA, and Mosaic slices an HBM array row by row only when a row
+is whole (1, 128) tiles of a 32-bit type. ``kernel_rows()`` is that
+payload: the dequantized float32 rows padded to a multiple of 128 lanes.
+An engine whose fused stages run as Pallas kernels holds its store in this
+layout (``with_kernel_layout``); the dtype tag stays, so bf16/int8
+residency keeps its rounding, but every row costs ``Dp * 4`` bytes
+whatever the dtype (512 B at D=40 — what XLA's (8, 128) tiling gives an
+f32[N, 40] array anyway).
 """
 from __future__ import annotations
 
@@ -61,6 +69,7 @@ from jax import lax
 from repro.obs.trace import NULL_TRACER
 
 CORPUS_DTYPES = ("float32", "bfloat16", "int8")
+LANES = 128                  # TPU vector lanes: the kernel layout's row unit
 RESIDENCY_KINDS = ("whole", "paged")
 
 _EPS = 1e-8
@@ -173,12 +182,17 @@ class CorpusStore:
     gather used everywhere the Pallas index-fused kernels don't run.
     ``tombstones`` is an optional packed (ceil(N/32),) uint32 bitmap of
     deleted rows (streaming deletes — the engine scores them -inf).
+
+    ``kernel_dim`` set means the kernel layout: ``data`` is the (N, Dp)
+    float32 ``kernel_rows()`` payload, already dequantized, whose first
+    ``kernel_dim`` lanes are the corpus (see the module docstring).
     """
 
     is_paged = False
 
     def __init__(self, data: jax.Array, scales: Optional[jax.Array],
-                 dtype: str, tombstones: Optional[jax.Array] = None):
+                 dtype: str, tombstones: Optional[jax.Array] = None,
+                 kernel_dim: Optional[int] = None):
         if dtype not in CORPUS_DTYPES:
             raise ValueError(f"corpus_dtype must be one of {CORPUS_DTYPES}, "
                              f"got {dtype!r}")
@@ -186,6 +200,7 @@ class CorpusStore:
         self.scales = scales
         self.dtype = dtype
         self.tombstones = tombstones
+        self.kernel_dim = kernel_dim
 
     @property
     def n(self) -> int:
@@ -193,6 +208,8 @@ class CorpusStore:
 
     @property
     def dim(self) -> int:
+        if self.kernel_dim is not None:
+            return self.kernel_dim
         return self.data.shape[-1]
 
     def take(self, ids: jax.Array, in_bounds: bool = False) -> jax.Array:
@@ -204,6 +221,8 @@ class CorpusStore:
         engine's tile plan uses it — its ids are clamped upstream."""
         mode = "clip" if in_bounds else None
         rows = jnp.take(self.data, ids, axis=0, mode=mode)
+        if self.kernel_dim is not None:
+            return rows[..., :self.kernel_dim]
         if self.dtype == "bfloat16":
             return bf16_bits_to_f32(rows)
         if self.dtype == "int8":
@@ -211,18 +230,33 @@ class CorpusStore:
                                                        axis=0, mode=mode)
         return rows.astype(jnp.float32)
 
-    def take_raw(self, ids: jax.Array) -> jax.Array:
-        """Gather rows in residency format (no dequant) — bf16/int8 gathers
-        move half / a quarter of the fp32 bytes."""
-        return jnp.take(self.data, ids, axis=0)
-
     def dequantize(self) -> jax.Array:
         """The full (N, D) float32 corpus (materializes!)."""
+        if self.kernel_dim is not None:
+            return self.data[:, :self.kernel_dim]
         if self.dtype == "bfloat16":
             return bf16_bits_to_f32(self.data)
         if self.dtype == "int8":
             return dequantize_rows_int8(self.data, self.scales)
         return self.data.astype(jnp.float32)
+
+    def kernel_rows(self) -> jax.Array:
+        """The (N, Dp) float32 payload the Pallas index-fused kernels DMA
+        from: dequantized rows padded to ``Dp``, a multiple of 128 lanes.
+        Free in the kernel layout; otherwise built here, one (N, Dp) pass
+        per call — engines hold their store in the kernel layout so that
+        this never runs inside a search."""
+        if self.kernel_dim is not None:
+            return self.data
+        rows = self.dequantize()
+        return jnp.pad(rows, ((0, 0), (0, -self.dim % LANES)))
+
+    def with_kernel_layout(self) -> "CorpusStore":
+        """This store in the kernel layout (a no-op if it already is)."""
+        if self.kernel_dim is not None:
+            return self
+        return CorpusStore(self.kernel_rows(), None, self.dtype,
+                           self.tombstones, kernel_dim=self.dim)
 
     def nbytes(self) -> int:
         """Resident payload bytes (data + scales)."""
@@ -235,20 +269,22 @@ class CorpusStore:
         """A view of this store with the given (N,) bool delete flags
         packed into the tombstone bitmap (None clears it)."""
         words = None if flags is None else jnp.asarray(pack_bitmap(flags))
-        return CorpusStore(self.data, self.scales, self.dtype, words)
+        return CorpusStore(self.data, self.scales, self.dtype, words,
+                           self.kernel_dim)
 
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
+        layout = "" if self.kernel_dim is None else ", kernel layout"
         return (f"CorpusStore(n={self.data.shape[0]}, dim={self.dim}, "
-                f"dtype={self.dtype})")
+                f"dtype={self.dtype}{layout})")
 
 
 def _store_flatten(s: CorpusStore):
-    return (s.data, s.scales, s.tombstones), s.dtype
+    return (s.data, s.scales, s.tombstones), (s.dtype, s.kernel_dim)
 
 
-def _store_unflatten(dtype, children):
+def _store_unflatten(aux, children):
     data, scales, tombstones = children
-    return CorpusStore(data, scales, dtype, tombstones)
+    return CorpusStore(data, scales, aux[0], tombstones, aux[1])
 
 
 jax.tree_util.register_pytree_node(CorpusStore, _store_flatten,

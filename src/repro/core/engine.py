@@ -497,6 +497,17 @@ class ExpansionEngine:
             c = self.c_max
         return min(c, max_degree)
 
+    # -- the corpus in this engine's residency format and layout: fused
+    #    stages that run as Pallas kernels gather from the lane-padded
+    #    kernel layout (core/corpus.py), built here once so no search or
+    #    serving tick pays for it. Paged stores pass through (init_state
+    #    refuses them with Pallas fused stages).
+    def prepare_store(self, base) -> CorpusStore:
+        store = as_corpus_store(base, self.corpus_dtype)
+        if self.pallas_fused and not store.is_paged:
+            store = store.with_kernel_layout()
+        return store
+
     # -- state init: seed pools with the entry points (one measure call).
     #    iter_caps: optional (Q,) per-lane expansion budgets (defaults to
     #    cfg.iters() — the pre-existing uniform cap).
@@ -745,7 +756,7 @@ class ExpansionEngine:
     @functools.cached_property
     def _run_jit(self):
         def run(params, base, neighbors, queries, entries, iter_caps, taus):
-            store = as_corpus_store(base, self.corpus_dtype)
+            store = self.prepare_store(base)
             state = self.init_state(params, store, neighbors, queries,
                                     entries, iter_caps, taus)
             C = self.n_candidates(neighbors.shape[1])
@@ -808,7 +819,7 @@ class ExpansionEngine:
                      = None, iter_caps=None, taus=None,
                      jit_steps: bool = True) -> SearchResult:
         entries = jnp.asarray(entries, jnp.int32)
-        store = as_corpus_store(base, self.corpus_dtype)
+        store = self.prepare_store(base)
         if jit_steps:
             init_fn, step_fn = self._debug_jits
             caps = jnp.full((queries.shape[0],), self.cfg.iters(),

@@ -271,20 +271,23 @@ def brute_force_topk(measure: Measure, base: jax.Array, queries: jax.Array,
     """Exact top-k by exhaustive measure evaluation (ground-truth labels —
     the paper's label protocol). Batched over queries AND corpus blocks: one
     jitted (Qb, Nb) scorer with a streaming top-k merge, instead of the old
-    per-query Python loop."""
+    per-query Python loop. Matmuls run at ``highest`` precision: the TPU's
+    default passes float32 operands through bfloat16, and a reference must
+    be float32."""
     base = jnp.asarray(base)
     queries = jnp.asarray(queries)
     step = _bf_merge_step(measure.score_fn)
     outs_i, outs_s = [], []
-    for q0 in range(0, queries.shape[0], q_block):
-        qb = queries[q0: q0 + q_block]
-        best_s = jnp.full((qb.shape[0], k), -jnp.inf, jnp.float32)
-        best_i = jnp.full((qb.shape[0], k), -1, jnp.int32)
-        for s in range(0, base.shape[0], batch):
-            best_s, best_i = step(measure.params, qb, base[s: s + batch],
-                                  jnp.int32(s), best_s, best_i)
-        outs_i.append(best_i)
-        outs_s.append(best_s)
+    with jax.default_matmul_precision("highest"):
+        for q0 in range(0, queries.shape[0], q_block):
+            qb = queries[q0: q0 + q_block]
+            best_s = jnp.full((qb.shape[0], k), -jnp.inf, jnp.float32)
+            best_i = jnp.full((qb.shape[0], k), -1, jnp.int32)
+            for s in range(0, base.shape[0], batch):
+                best_s, best_i = step(measure.params, qb, base[s: s + batch],
+                                      jnp.int32(s), best_s, best_i)
+            outs_i.append(best_i)
+            outs_s.append(best_s)
     return jnp.concatenate(outs_i), jnp.concatenate(outs_s)
 
 

@@ -28,7 +28,6 @@ from repro.core.engine import EngineOptions, build_engine_from_fn
 from repro.core.measures import Measure
 from repro.core.search import SearchConfig, SearchResult
 from repro.graph.build import GraphIndex, build_l2_graph
-from repro.utils import shard_map_compat
 
 
 @dataclasses.dataclass
@@ -142,7 +141,7 @@ def make_sharded_search(score_fn, mesh: Mesh, cfg: SearchConfig,
         return jax.tree_util.tree_map(lambda _: P(), tree)
 
     def fn(measure_params, base, nbrs, entries, gids, queries):
-        wrapped = shard_map_compat(
+        wrapped = jax.shard_map(
             local_search, mesh=mesh,
             in_specs=(specs_like(measure_params),
                       P(axis, None, None), P(axis, None, None), P(axis),
@@ -151,10 +150,22 @@ def make_sharded_search(score_fn, mesh: Mesh, cfg: SearchConfig,
                 ids=P(batch_axes, None), scores=P(batch_axes, None),
                 n_eval=P(batch_axes), n_grad=P(batch_axes),
                 n_iters=P(batch_axes)),
-            check=False)
+            check_vma=False)
         return wrapped(measure_params, base, nbrs, entries, gids, queries)
 
     return jax.jit(fn)
+
+
+def place_index(index: ShardedIndex, mesh: Mesh) -> Tuple[jax.Array, ...]:
+    """Put each shard's (base, neighbors, entry, global ids) on the device
+    that owns it along the mesh's ``model`` axis — the layout
+    ``make_sharded_search`` takes, so no device ever holds the whole
+    corpus."""
+    def put(x):
+        spec = P("model", *([None] * (np.ndim(x) - 1)))
+        return jax.device_put(x, NamedSharding(mesh, spec))
+    return tuple(put(x) for x in (index.base, index.neighbors,
+                                  index.entries, index.global_ids))
 
 
 def sharded_search_host(measure: Measure, index: ShardedIndex,
@@ -171,9 +182,7 @@ def sharded_search_host(measure: Measure, index: ShardedIndex,
     bundle per shard (registry routing is shard-transparent)."""
     fn = make_sharded_search(measure.score_fn, mesh, cfg, options,
                              meta=getattr(measure, "meta", None))
-    args = (measure.params, jnp.asarray(index.base),
-            jnp.asarray(index.neighbors), jnp.asarray(index.entries),
-            jnp.asarray(index.global_ids), jnp.asarray(queries))
+    args = (measure.params, *place_index(index, mesh), jnp.asarray(queries))
     return SearchResult(*[np.asarray(x) for x in fn(*args)])
 
 

@@ -6,14 +6,15 @@ ops.py (jit'd public wrapper with interpret/fallback switches), ref.py
 
   deepfm_score   fused candidate-batch DeepFM measure evaluation (the GUITAR
                  search inner loop — FM dot + 2-layer MLP in one VMEM pass)
-  neighbor_rank  fused gradient ranking: diffs, norms, separation angle /
-                 projection, adaptive α·θ mask (Eq. 3/4) per frontier
+  neighbor_rank  fused gradient ranking: diffs, norms, cosine / projection
+                 per frontier (the angle and the adaptive α·θ mask of
+                 Eq. 3/4 are taken on its output — Mosaic has no acos)
   deepfm_score_fused / neighbor_rank_fused
                  index-fused variants: (corpus, idx) in, scores out — the
                  row gather runs inside the kernel via scalar-prefetch
-                 indexing over the (fp32/bf16/int8) resident corpus, so the
-                 pre-gathered (Q·C, D) / (Q, B, D) blocks never hit HBM
-                 (quant.py holds the shared in-kernel dequant)
+                 indexing over the store's lane-padded f32 kernel payload
+                 (core/corpus.py), so the pre-gathered (Q·C, D) /
+                 (Q, B, D) blocks never hit HBM
   deepfm_grad / deepfm_grad_fused
                  analytic forward+backward for the GUITAR grad stage (the
                  cost the paper charges double) — fp32 refs bit-match

@@ -13,7 +13,8 @@ forward structure from a transposed graph.
 
 Tiling mirrors ``deepfm_score``: grid over row blocks, weights whole in
 VMEM (measure MLPs are tiny), transposed weights passed pre-materialized by
-ops.py so the backward matmuls are plain MXU contractions.
+ops.py so the backward matmuls are plain MXU contractions. Values leave as
+a (BN, 1) column.
 """
 from __future__ import annotations
 
@@ -23,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.mxu import dot_f32
+
 
 def _kernel(cand_ref, query_ref, w0_ref, b0_ref, w1_ref, b1_ref, w2_ref,
             b2_ref, w0t_ref, w1t_ref, w2t_ref, val_ref, grad_ref, *,
@@ -30,32 +33,29 @@ def _kernel(cand_ref, query_ref, w0_ref, b0_ref, w1_ref, b1_ref, w2_ref,
     x = cand_ref[...]                          # (BN, D)
     q = query_ref[...]                         # (BN, D) or (1, D) shared
     BN = x.shape[0]
-    fm = jnp.sum(x[:, :fm_dim] * q[:, :fm_dim], axis=-1)          # (BN,)
+    fm = jnp.sum(x[:, :fm_dim] * q[:, :fm_dim], axis=-1,
+                 keepdims=True)                                   # (BN, 1)
     q_deep = jnp.broadcast_to(q[:, fm_dim: fm_dim + deep_dim],
                               (BN, deep_dim))
     deep_in = jnp.concatenate(
         [q_deep, x[:, fm_dim: fm_dim + deep_dim]], axis=-1)       # (BN, 2dd)
-    z0 = jnp.dot(deep_in, w0_ref[...],
-                 preferred_element_type=jnp.float32) + b0_ref[...][None, :]
+    z0 = dot_f32(deep_in, w0_ref[...]) + b0_ref[...][None, :]
     h0 = jnp.maximum(z0, 0.0)
-    z1 = jnp.dot(h0, w1_ref[...],
-                 preferred_element_type=jnp.float32) + b1_ref[...][None, :]
+    z1 = dot_f32(h0, w1_ref[...]) + b1_ref[...][None, :]
     h1 = jnp.maximum(z1, 0.0)
-    logit = jnp.dot(h1, w2_ref[...],
-                    preferred_element_type=jnp.float32)[:, 0]
-    val = jax.nn.sigmoid(logit + b2_ref[...][0] + fm)             # (BN,)
+    logit = dot_f32(h1, w2_ref[...])
+    val = jax.nn.sigmoid(logit + b2_ref[...][0] + fm)             # (BN, 1)
     # backward — activations still resident in VMEM
-    g_logit = val * (1.0 - val)                                   # (BN,)
-    g1 = g_logit[:, None] * w2t_ref[...]                          # (BN, H2)
+    g_logit = val * (1.0 - val)                                   # (BN, 1)
+    g1 = g_logit * w2t_ref[...]                                   # (BN, H2)
     g1 = jnp.where(z1 > 0, g1, 0.0)
-    g0 = jnp.dot(g1, w1t_ref[...], preferred_element_type=jnp.float32)
+    g0 = dot_f32(g1, w1t_ref[...])
     g0 = jnp.where(z0 > 0, g0, 0.0)
-    g_in = jnp.dot(g0, w0t_ref[...],
-                   preferred_element_type=jnp.float32)            # (BN, 2dd)
+    g_in = dot_f32(g0, w0t_ref[...])            # (BN, 2dd)
     q_fm = jnp.broadcast_to(q[:, :fm_dim], (BN, fm_dim))
     val_ref[...] = val
     grad_ref[...] = jnp.concatenate(
-        [g_logit[:, None] * q_fm, g_in[:, deep_dim:]], axis=-1)
+        [g_logit * q_fm, g_in[:, deep_dim:]], axis=-1)
 
 
 @functools.partial(jax.jit, static_argnames=("fm_dim", "deep_dim", "block_n",
@@ -72,7 +72,7 @@ def deepfm_grad_pallas(cand: jax.Array, query: jax.Array, w0, b0, w1, b1,
     row_spec = pl.BlockSpec((block_n, D), lambda i: (i, 0))
     q_spec = pl.BlockSpec((1, D), lambda i: (0, 0)) if q_shared else row_spec
     full = lambda *s: pl.BlockSpec(s, lambda i: tuple(0 for _ in s))
-    return pl.pallas_call(
+    vals, grads = pl.pallas_call(
         functools.partial(_kernel, fm_dim=fm_dim, deep_dim=deep_dim),
         grid=grid,
         in_specs=[
@@ -82,9 +82,11 @@ def deepfm_grad_pallas(cand: jax.Array, query: jax.Array, w0, b0, w1, b1,
             full(*w2.shape), full(*b2.shape),
             full(*w0.T.shape), full(*w1.T.shape), full(*w2t.shape),
         ],
-        out_specs=(pl.BlockSpec((block_n,), lambda i: (i,)),
+        out_specs=(pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
                    pl.BlockSpec((block_n, D), lambda i: (i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((N,), jnp.float32),
+        out_shape=(jax.ShapeDtypeStruct((N, 1), jnp.float32),
                    jax.ShapeDtypeStruct((N, D), jnp.float32)),
         interpret=interpret,
+        name="deepfm_grad",
     )(cand, query, w0, b0, w1, b1, w2, b2, w0.T, w1.T, w2t)
+    return vals[:, 0], grads

@@ -37,7 +37,7 @@ def deepfm_grad_fused(store: CorpusStore, idx: jax.Array, query: jax.Array,
         "deepfm_grad_fused", q=int(idx.shape[0]), m=0, d=int(store.dim),
         dtype=store.dtype, override=autotune.parse_tile(tile))
     return deepfm_grad_fused_pallas(
-        store.data, store.scales, idx, query.astype(jnp.float32),
+        store.kernel_rows(), idx, query.astype(jnp.float32),
         w[0], b[0], w[1], b[1], w[2], b[2],
         fm_dim=fm_dim, deep_dim=store.dim - fm_dim, interpret=interpret,
         bt=cfg.bt)

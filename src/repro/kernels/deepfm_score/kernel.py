@@ -4,7 +4,8 @@ The GUITAR search inner loop evaluates f(x, q) over a (candidates,) batch per
 step. On TPU this wants to be ONE VMEM-resident fusion: load a tile of
 candidate/query rows, compute the FM dot on the VPU, run the two small MLP
 matmuls back-to-back on the MXU without spilling the 64-wide hidden
-activations to HBM, and write a single score lane back.
+activations to HBM, and write a (BLOCK_N, 1) score column back — the last
+matmul's own layout (a rank-1 block would have to be a multiple of 128).
 
 Tiling: grid over row blocks (BLOCK_N rows). Feature dims are padded to
 lane-friendly sizes by ops.py (deep-in = 64, hidden = 64 — the MXU pads to
@@ -19,24 +20,27 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.mxu import dot_f32
+
 
 def _kernel(cand_ref, query_ref, w0_ref, b0_ref, w1_ref, b1_ref, w2_ref,
             b2_ref, out_ref, *, fm_dim: int, deep_dim: int):
     cand = cand_ref[...]                       # (BN, D)
     query = query_ref[...]                     # (BN, D) or (1, D) shared
-    fm = jnp.sum(cand[:, :fm_dim] * query[:, :fm_dim], axis=-1)  # (BN,)
+    fm = jnp.sum(cand[:, :fm_dim] * query[:, :fm_dim], axis=-1,
+                 keepdims=True)                # (BN, 1)
     q_deep = jnp.broadcast_to(query[:, fm_dim: fm_dim + deep_dim],
                               (cand.shape[0], deep_dim))  # VMEM-only bcast
     deep_in = jnp.concatenate(
         [q_deep, cand[:, fm_dim: fm_dim + deep_dim]],
         axis=-1)                               # (BN, 2*deep_dim)
     h = jnp.maximum(
-        jnp.dot(deep_in, w0_ref[...], preferred_element_type=jnp.float32)
+        dot_f32(deep_in, w0_ref[...])
         + b0_ref[...][None, :], 0.0)
     h = jnp.maximum(
-        jnp.dot(h, w1_ref[...], preferred_element_type=jnp.float32)
+        dot_f32(h, w1_ref[...])
         + b1_ref[...][None, :], 0.0)
-    logit = jnp.dot(h, w2_ref[...], preferred_element_type=jnp.float32)[:, 0]
+    logit = dot_f32(h, w2_ref[...])
     logit = logit + b2_ref[...][0] + fm
     out_ref[...] = jax.nn.sigmoid(logit)
 
@@ -65,7 +69,8 @@ def deepfm_score_pallas(cand: jax.Array, query: jax.Array, w0, b0, w1, b1,
             full(*w1.shape), full(*b1.shape),
             full(*w2.shape), full(*b2.shape),
         ],
-        out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((N,), jnp.float32),
+        out_specs=pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, 1), jnp.float32),
         interpret=interpret,
-    )(cand, query, w0, b0, w1, b1, w2, b2)
+        name="deepfm_score",
+    )(cand, query, w0, b0, w1, b1, w2, b2)[:, 0]

@@ -6,15 +6,18 @@ candidate block that the engine had to stage through HBM. This variant
 takes the resident corpus and the (M,) candidate-id vector and gathers
 *inside* the kernel — but instead of the original one-row-per-grid-step
 BlockSpec gather, each grid step now DMAs ``bt`` candidate rows into a
-double-buffered (2, bt, D) VMEM tile (``kernels/dma.py``): step ``t+1``'s
+double-buffered (2, bt, Dp) VMEM tile (``kernels/dma.py``): step ``t+1``'s
 row copies are issued before step ``t`` computes, so the gather hides
 behind the tile's MLP, and the per-step compute is a real (bt, 2·deep)
 matmul instead of a GEMV. ``bt`` comes from the autotune cache
-(``kernels/autotune.py``); ``bt=1`` reproduces the old schedule.
+(``kernels/autotune.py``); on TPU it must be a multiple of 8, the
+sublane tile of the (bt, 1) score column.
 
-With bf16/int8 residency the gather moves 2x/4x fewer bytes and the
-dequant (int8: per-row scale tile, gathered on the same schedule) happens
-in VMEM.
+The kernel gathers from the store's kernel payload (``CorpusStore.
+kernel_rows``: dequantized float32 rows padded to 128 lanes), so bf16 and
+int8 residency reach it already rounded and it holds no dequant code.
+Scores leave as a (bt, 1) column per step: the MLP's last matmul yields
+that layout, and a rank-1 block would have to be a multiple of 128.
 """
 from __future__ import annotations
 
@@ -25,51 +28,40 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dma import RowGather, schedule_double_buffer
-from repro.kernels.quant import rows_f32
+from repro.kernels.dma import RowGather, gather_scratch
+from repro.kernels.mxu import dot_f32
 
 
 def _score_tile(rows, q, w0_ref, b0_ref, w1_ref, b1_ref, w2_ref, b2_ref, *,
                 fm_dim: int, deep_dim: int):
-    """rows/q: (bt, D) f32 tiles -> (bt,) scores."""
-    fm = jnp.sum(rows[:, :fm_dim] * q[:, :fm_dim], axis=1)
+    """rows/q: (bt, D) f32 tiles -> (bt, 1) scores."""
+    fm = jnp.sum(rows[:, :fm_dim] * q[:, :fm_dim], axis=1, keepdims=True)
     deep_in = jnp.concatenate(
         [q[:, fm_dim: fm_dim + deep_dim], rows[:, fm_dim: fm_dim + deep_dim]],
         axis=1)                                           # (bt, 2*deep)
     h = jnp.maximum(
-        jnp.dot(deep_in, w0_ref[...], preferred_element_type=jnp.float32)
+        dot_f32(deep_in, w0_ref[...])
         + b0_ref[...][None, :], 0.0)
     h = jnp.maximum(
-        jnp.dot(h, w1_ref[...], preferred_element_type=jnp.float32)
+        dot_f32(h, w1_ref[...])
         + b1_ref[...][None, :], 0.0)
-    logit = jnp.dot(h, w2_ref[...], preferred_element_type=jnp.float32)[:, 0]
+    logit = dot_f32(h, w2_ref[...])
     return jax.nn.sigmoid(logit + b2_ref[...][0] + fm)
 
 
 def _kernel(idx_ref, *refs, fm_dim: int, deep_dim: int, bt: int,
-            quant: bool, q_shared: bool, masked: bool):
+            q_shared: bool, masked: bool):
     refs = list(refs)
     data_ref = refs.pop(0)
-    scales_ref = refs.pop(0) if quant else None
     mask_ref = refs.pop(0) if masked else None
-    q_ref, w0, b0, w1, b1, w2, b2, out_ref = refs[:8]
-    if quant:
-        vmem, svmem, dsem, ssem = refs[8:]
-    else:
-        vmem, dsem = refs[8:]
-    t = pl.program_id(0)
+    q_ref, w0, b0, w1, b1, w2, b2, out_ref, vmem, sem = refs
     # the double-buffer schedule runs UNCONDITIONALLY — step t issues step
     # t+1's row copies, so skipping it inside a masked tile would starve
     # the next tile's gather
-    gathers = [RowGather(idx_ref, data_ref, vmem, dsem, bt)]
-    if quant:
-        gathers.append(RowGather(idx_ref, scales_ref, svmem, ssem, bt))
-    slot = schedule_double_buffer(t, gathers)
+    rows = RowGather(idx_ref, data_ref, vmem, sem, bt).rows(
+        pl.program_id(0), fm_dim + deep_dim)              # (bt, D)
 
     def _scores():
-        rows = rows_f32(vmem[slot])                       # (bt, D)
-        if quant:
-            rows = rows * svmem[slot]                     # (bt, 1) scales
         q = q_ref[...]
         if q_shared:
             q = jnp.broadcast_to(q, (bt, q.shape[-1]))
@@ -79,7 +71,7 @@ def _kernel(idx_ref, *refs, fm_dim: int, deep_dim: int, bt: int,
     if not masked:
         out_ref[...] = _scores()
     else:
-        # adaptive prefix mask: per-lane dynamic |C| arrives as a (bt,)
+        # adaptive prefix mask: per-lane dynamic |C| arrives as a (bt, 1)
         # mask tile on the same grid as the tail padding; a fully-masked
         # tile skips the FM + MLP entirely (the DMA already ran — the
         # pipeline stays sound) and writes the -inf sentinel the engine's
@@ -93,51 +85,41 @@ def _kernel(idx_ref, *refs, fm_dim: int, deep_dim: int, bt: int,
 
         @pl.when(~any_live)
         def _():
-            out_ref[...] = jnp.full((bt,), -jnp.inf, jnp.float32)
+            out_ref[...] = jnp.full((bt, 1), -jnp.inf, jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("fm_dim", "deep_dim",
                                              "q_shared", "interpret", "bt"))
-def deepfm_score_fused_pallas(data, scales, idx, query, w0, b0, w1, b1,
-                              w2, b2, *, fm_dim: int = 8, deep_dim: int = 32,
+def deepfm_score_fused_pallas(rows, idx, query, w0, b0, w1, b1, w2, b2, *,
+                              fm_dim: int = 8, deep_dim: int = 32,
                               q_shared: bool = False,
                               interpret: bool = False,
                               bt: int = 8, mask=None) -> jax.Array:
-    """data: (N, D) resident corpus (f32/bf16/int8); scales: (N, 1) f32 for
-    int8 else None; idx: (M,) int32 (pre-clamped >= 0); query: (M, D) rows,
-    or (1, D) shared across candidates when ``q_shared`` (the kernel
-    broadcasts — no (M, D) query copy is ever built); bt: candidate rows
-    per grid step (autotuned; M is padded up to a multiple); mask: optional
-    (M,) bool — masked rows score -inf and all-masked ``bt`` tiles skip
-    their compute (the mask pads with False onto the same grid tail as
-    ``idx``)."""
+    """rows: (N, Dp) f32 kernel payload (``CorpusStore.kernel_rows``) whose
+    first fm_dim + deep_dim lanes are the corpus; idx: (M,) int32
+    (pre-clamped >= 0); query: (M, D) rows, or (1, D) shared across
+    candidates when ``q_shared`` (the kernel broadcasts — no (M, D) query
+    copy is ever built); bt: candidate rows per grid step (autotuned; M is
+    padded up to a multiple); mask: optional (M,) bool — masked rows score
+    -inf and all-masked ``bt`` tiles skip their compute (the mask pads with
+    False onto the same grid tail as ``idx``)."""
     M = idx.shape[0]
-    D = data.shape[1]
-    quant = scales is not None
     masked = mask is not None
-    bt = max(1, min(int(bt), M))
+    bt = max(1, int(bt))
     mp = -(-M // bt) * bt
     idx = jnp.pad(idx, (0, mp - M))
     if not q_shared:
         query = jnp.pad(query, ((0, mp - M), (0, 0)))
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
     full = lambda *s: pl.BlockSpec(s, lambda t, idx_ref: tuple(0 for _ in s))
+    col = pl.BlockSpec((bt, 1), lambda t, idx_ref: (t, 0))
     q_spec = full(1, query.shape[1]) if q_shared \
         else pl.BlockSpec((bt, query.shape[1]), lambda t, idx_ref: (t, 0))
-    in_specs = [any_spec]
-    args = [data]
-    scratch = [pltpu.VMEM((2, bt, D), data.dtype)]
-    if quant:
-        in_specs.append(any_spec)
-        args.append(scales)
-        scratch.append(pltpu.VMEM((2, bt, 1), jnp.float32))
-    scratch.append(pltpu.SemaphoreType.DMA((2, bt)))
-    if quant:
-        scratch.append(pltpu.SemaphoreType.DMA((2, bt)))
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+    args = [rows]
     if masked:
         # int32 0/1 tiles (bool HBM tensors don't lay out portably on TPU)
-        in_specs.append(pl.BlockSpec((bt,), lambda t, idx_ref: (t,)))
-        args.append(jnp.pad(mask.astype(jnp.int32), (0, mp - M)))
+        in_specs.append(col)
+        args.append(jnp.pad(mask.astype(jnp.int32), (0, mp - M))[:, None])
     in_specs += [
         q_spec,
         full(*w0.shape), full(*b0.shape),
@@ -149,14 +131,15 @@ def deepfm_score_fused_pallas(data, scales, idx, query, w0, b0, w1, b1,
         num_scalar_prefetch=1,
         grid=(mp // bt,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bt,), lambda t, idx_ref: (t,)),
-        scratch_shapes=scratch,
+        out_specs=col,
+        scratch_shapes=gather_scratch(bt, rows.shape[1]),
     )
     out = pl.pallas_call(
         functools.partial(_kernel, fm_dim=fm_dim, deep_dim=deep_dim, bt=bt,
-                          quant=quant, q_shared=q_shared, masked=masked),
+                          q_shared=q_shared, masked=masked),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((mp,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((mp, 1), jnp.float32),
         interpret=interpret,
+        name="deepfm_score_fused",
     )(idx, *args)
-    return out[:M]
+    return out[:M, 0]

@@ -47,7 +47,7 @@ def deepfm_score_fused(store: CorpusStore, idx: jax.Array, query: jax.Array,
     q_shared = query.ndim == 1
     q_arg = query[None, :] if q_shared else query
     return deepfm_score_fused_pallas(
-        store.data, store.scales, idx, q_arg.astype(jnp.float32),
+        store.kernel_rows(), idx, q_arg.astype(jnp.float32),
         w[0], b[0], w[1], b[1], w[2], b[2],
         fm_dim=fm_dim, deep_dim=store.dim - fm_dim, q_shared=q_shared,
         interpret=interpret, bt=cfg.bt, mask=mask)

@@ -1,12 +1,8 @@
 """Double-buffered multi-row DMA gather for the wide-block fused kernels.
 
-The original fused kernels leaned on the Pallas pipeline for their gather:
-a (1, D) corpus BlockSpec whose index map reads the scalar-prefetched id,
-one row per grid step. That shape caps the compute at single-row GEMVs and
-gives the pipeline only one row of lookahead. The wide-block kernels
-instead keep the corpus in ``TPUMemorySpace.ANY`` and gather ``bt`` rows
-per grid step with explicit per-row async copies into a (2, bt, ...) VMEM
-scratch tile:
+The fused kernels keep the corpus in ``pl.ANY`` (HBM) and gather ``bt``
+rows per grid step with explicit per-row async copies into a (2, bt, Dp)
+VMEM scratch tile:
 
     slot 0             slot 1
     [tile t compute]   [tile t+1 DMA in flight]
@@ -14,9 +10,15 @@ scratch tile:
 Step ``t`` issues tile ``t+1``'s copies *before* waiting on its own rows,
 so the next gather overlaps the current tile's (bt, ·) matmuls. Grids are
 linearized to 1-D by the callers so the tile index is just ``program_id``.
+
+The source is the store's kernel payload (``CorpusStore.kernel_rows``):
+float32 rows padded to a multiple of 128 lanes. Mosaic slices an HBM array
+one row at a time only when the row is a whole number of (1, 128) 32-bit
+tiles — a 40-wide row, or any 16- or 8-bit row, is refused.
 """
 from __future__ import annotations
 
+import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -27,8 +29,8 @@ class RowGather:
 
     idx_ref:     scalar-prefetch ref holding the flat (n_tiles * bt,) row
                  ids (callers pad/linearize)
-    src_ref:     (N, ...) source in ANY memory (corpus data or row scales)
-    scratch_ref: (2, bt, ...) VMEM scratch
+    src_ref:     (N, Dp) float32 kernel payload in ANY memory
+    scratch_ref: (2, bt, Dp) VMEM scratch
     sem_ref:     (2, bt) DMA semaphores
     """
 
@@ -53,23 +55,26 @@ class RowGather:
         for j in range(self.bt):
             self._dma(slot, tile, j).wait()
 
+    def rows(self, t, dim: int):
+        """Run the warm-up / prefetch / wait schedule for grid step ``t``
+        and return its (bt, dim) f32 rows (the lane padding dropped)."""
+        nt = pl.num_programs(0)
 
-def schedule_double_buffer(t, gathers):
-    """The warm-up / prefetch / wait schedule for grid step ``t`` over a
-    list of ``RowGather``s (data + scales share one schedule). Returns the
-    slot index holding step ``t``'s rows, ready to read."""
-    nt = pl.num_programs(0)
+        @pl.when(t == 0)
+        def _():
+            self.start(0, 0)
 
-    @pl.when(t == 0)
-    def _():
-        for g in gathers:
-            g.start(0, 0)
+        @pl.when(t + 1 < nt)
+        def _():
+            self.start((t + 1) % 2, t + 1)
 
-    @pl.when(t + 1 < nt)
-    def _():
-        for g in gathers:
-            g.start((t + 1) % 2, t + 1)
+        self.wait(t % 2, t)
+        return self.scratch_ref[t % 2][:, :dim]
 
-    for g in gathers:
-        g.wait(t % 2, t)
-    return t % 2
+
+def gather_scratch(bt: int, width: int):
+    """The scratch shapes one ``RowGather`` needs: the (2, bt, width) f32
+    double buffer and its (2, bt) DMA semaphores."""
+    import jax.numpy as jnp
+    return [pltpu.VMEM((2, bt, width), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, bt))]

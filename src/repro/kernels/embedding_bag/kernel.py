@@ -28,7 +28,7 @@ def _kernel(idx_ref, w_ref, table_ref, out_ref, *, bag_len: int,
         row = idx_ref[b0 + bag, slot]
         valid = row >= 0
         safe = jnp.maximum(row, 0)
-        vec = pl.load(table_ref, (pl.dslice(safe, 1), slice(None)))[0]
+        vec = table_ref[pl.ds(safe, 1), :][0]
         w = jnp.where(valid, w_ref[b0 + bag, slot], 0.0)
         return acc.at[bag].add(vec * w)
 
@@ -49,7 +49,7 @@ def embedding_bag_pallas(table: jax.Array, indices: jax.Array,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,           # indices, weights
             grid=grid,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],   # table in HBM
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],   # table in HBM
             out_specs=pl.BlockSpec((block_b, d), lambda i, idx, w: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((B, d), table.dtype),

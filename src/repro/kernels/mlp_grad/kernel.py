@@ -4,13 +4,14 @@ index-fused) — the GUITAR grad stage for the generic MLP measure.
 One VMEM pass per row block: forward concat + L matmuls keeping every
 pre-activation resident, then the hand-derived backward (sigmoid
 derivative, transposed matmuls with relu masks off the resident
-pre-activations), writing the value lane and the df/dx gradient rows.
+pre-activations), writing a (BN, 1) value column and the df/dx gradient
+rows.
 ops.py passes the transposed weights pre-materialized so the backward
 matmuls are plain MXU contractions. The fused variant gathers ``bt``
-frontier rows per grid step (autotuned — kernels/autotune.py) into a
-double-buffered VMEM tile (dequant-on-gather) and also writes the
-dequantized rows out for the rank stage — the (Q, Dx) frontier block never
-stages through fp32 HBM.
+frontier rows per grid step (autotuned — kernels/autotune.py) of the
+store's kernel payload (``CorpusStore.kernel_rows``) into a double-buffered
+VMEM tile and also writes the rows out for the rank stage — the engine
+never gathers the (Q, Dx) frontier block itself.
 """
 from __future__ import annotations
 
@@ -21,27 +22,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dma import RowGather, schedule_double_buffer
-from repro.kernels.quant import rows_f32
+from repro.kernels.dma import RowGather, gather_scratch
+from repro.kernels.mxu import dot_f32
 
 
 def _value_and_grad(h, wb_refs, wt_refs, n_layers: int, d_x: int):
-    """h: (BN, Dx+Dq) concat block. Returns (val (BN,), gx (BN, d_x))."""
+    """h: (BN, Dx+Dq) concat block. Returns (val (BN, 1), gx (BN, d_x))."""
     zs = []
     for i in range(n_layers):
         w = wb_refs[2 * i][...]
         b = wb_refs[2 * i + 1][...]
-        z = jnp.dot(h, w, preferred_element_type=jnp.float32) + b[None, :]
+        z = dot_f32(h, w) + b[None, :]
         zs.append(z)
         h = jnp.maximum(z, 0.0) if i < n_layers - 1 else z
-    val = jax.nn.sigmoid(h[:, 0])
-    g = (val * (1.0 - val))[:, None]                      # (BN, 1)
+    val = jax.nn.sigmoid(h[:, :1])                        # (BN, 1)
+    g = val * (1.0 - val)
     for i in range(n_layers - 1, -1, -1):
         wt = wt_refs[i][...]
         if wt.shape[0] == 1:                              # last layer: a row
             g = g * wt                                    # (BN, H) via VPU
         else:
-            g = jnp.dot(g, wt, preferred_element_type=jnp.float32)
+            g = dot_f32(g, wt)
         if i > 0:
             g = jnp.where(zs[i - 1] > 0, g, 0.0)
     return val, g[:, :d_x]
@@ -83,43 +84,30 @@ def mlp_grad_pallas(cand: jax.Array, query: jax.Array, *wbt,
         if q_shared else pl.BlockSpec((block_n, query.shape[1]),
                                       lambda i: (i, 0))
     full = lambda *s: pl.BlockSpec(s, lambda i: tuple(0 for _ in s))
-    return pl.pallas_call(
+    vals, grads = pl.pallas_call(
         functools.partial(_kernel, n_layers=n_layers, d_x=d_x),
         grid=grid,
         in_specs=[row_spec, q_spec] + [full(*a.shape) for a in wbt],
-        out_specs=(pl.BlockSpec((block_n,), lambda i: (i,)),
+        out_specs=(pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
                    pl.BlockSpec((block_n, d_x), lambda i: (i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((N,), jnp.float32),
+        out_shape=(jax.ShapeDtypeStruct((N, 1), jnp.float32),
                    jax.ShapeDtypeStruct((N, d_x), jnp.float32)),
         interpret=interpret,
+        name="mlp_grad",
     )(cand, query, *wbt)
+    return vals[:, 0], grads
 
 
-def _kernel_fused(idx_ref, *refs, n_layers: int, d_x: int, bt: int,
-                  quant: bool):
+def _kernel_fused(idx_ref, *refs, n_layers: int, d_x: int, bt: int):
     """Wide-block fused grad: ``bt`` frontier rows per grid step, DMAed
     into a double-buffered VMEM tile (``kernels/dma.py``) so the next
     tile's gather overlaps this tile's forward+backward."""
-    if quant:
-        data_ref, scales_ref, rest = refs[0], refs[1], refs[2:]
-    else:
-        data_ref, rest = refs[0], refs[1:]
-    q_ref = rest[0]
-    wb_refs = rest[1: 1 + 2 * n_layers]
-    wt_refs = rest[1 + 2 * n_layers: 1 + 3 * n_layers]
-    if quant:
-        (val_ref, grad_ref, x_ref,
-         vmem, svmem, dsem, ssem) = rest[1 + 3 * n_layers:]
-    else:
-        val_ref, grad_ref, x_ref, vmem, dsem = rest[1 + 3 * n_layers:]
-    t = pl.program_id(0)
-    gathers = [RowGather(idx_ref, data_ref, vmem, dsem, bt)]
-    if quant:
-        gathers.append(RowGather(idx_ref, scales_ref, svmem, ssem, bt))
-    slot = schedule_double_buffer(t, gathers)
-    rows = rows_f32(vmem[slot])                           # (bt, Dx)
-    if quant:
-        rows = rows * svmem[slot]
+    data_ref, q_ref = refs[0], refs[1]
+    wb_refs = refs[2: 2 + 2 * n_layers]
+    wt_refs = refs[2 + 2 * n_layers: 2 + 3 * n_layers]
+    val_ref, grad_ref, x_ref, vmem, sem = refs[2 + 3 * n_layers:]
+    rows = RowGather(idx_ref, data_ref, vmem, sem, bt).rows(
+        pl.program_id(0), d_x)                            # (bt, Dx)
     h = jnp.concatenate([rows, q_ref[...]], axis=-1)
     val, gx = _value_and_grad(h, wb_refs, wt_refs, n_layers, d_x)
     val_ref[...] = val
@@ -127,52 +115,37 @@ def _kernel_fused(idx_ref, *refs, n_layers: int, d_x: int, bt: int,
     x_ref[...] = rows
 
 
-@functools.partial(jax.jit, static_argnames=("n_layers", "interpret", "bt"))
-def mlp_grad_fused_pallas(data, scales, idx, query, *wbt, n_layers: int,
+@functools.partial(jax.jit, static_argnames=("n_layers", "d_x", "interpret",
+                                             "bt"))
+def mlp_grad_fused_pallas(rows, idx, query, *wbt, n_layers: int, d_x: int,
                           interpret: bool = False, bt: int = 8):
-    """data: (N, Dx) resident corpus; scales: (N, 1) f32 for int8 else None;
-    idx: (Q,) int32 frontier ids (pre-clamped >= 0); query: (Q, Dq) per-lane
-    rows; bt: lanes per grid step (autotuned; Q is padded up to a multiple).
-    Returns (vals (Q,), grads (Q, Dx), x (Q, Dx))."""
+    """rows: (N, Dp) f32 kernel payload (``CorpusStore.kernel_rows``) whose
+    first ``d_x`` lanes are the corpus; idx: (Q,) int32 frontier ids
+    (pre-clamped >= 0); query: (Q, Dq) per-lane rows; bt: lanes per grid
+    step (autotuned; Q is padded up to a multiple). Returns (vals (Q,),
+    grads (Q, Dx), x (Q, Dx))."""
     Q = idx.shape[0]
-    D = data.shape[1]
-    quant = scales is not None
-    bt = max(1, min(int(bt), Q))
+    bt = max(1, int(bt))
     qp = -(-Q // bt) * bt
     idx = jnp.pad(idx, (0, qp - Q))
     query = jnp.pad(query, ((0, qp - Q), (0, 0)))
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
     full = lambda *s: pl.BlockSpec(s, lambda t, idx_ref: tuple(0 for _ in s))
-    in_specs = [any_spec]
-    args = [data]
-    scratch = [pltpu.VMEM((2, bt, D), data.dtype)]
-    if quant:
-        in_specs.append(any_spec)
-        args.append(scales)
-        scratch.append(pltpu.VMEM((2, bt, 1), jnp.float32))
-    scratch.append(pltpu.SemaphoreType.DMA((2, bt)))
-    if quant:
-        scratch.append(pltpu.SemaphoreType.DMA((2, bt)))
-    in_specs += [pl.BlockSpec((bt, query.shape[1]),
-                              lambda t, idx_ref: (t, 0))]
-    in_specs += [full(*a.shape) for a in wbt]
-    args += [query, *wbt]
+    tile = lambda w: pl.BlockSpec((bt, w), lambda t, idx_ref: (t, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(qp // bt,),
-        in_specs=in_specs,
-        out_specs=(pl.BlockSpec((bt,), lambda t, idx_ref: (t,)),
-                   pl.BlockSpec((bt, D), lambda t, idx_ref: (t, 0)),
-                   pl.BlockSpec((bt, D), lambda t, idx_ref: (t, 0))),
-        scratch_shapes=scratch,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY), tile(query.shape[1])]
+        + [full(*a.shape) for a in wbt],
+        out_specs=(tile(1), tile(d_x), tile(d_x)),
+        scratch_shapes=gather_scratch(bt, rows.shape[1]),
     )
     vals, grads, x = pl.pallas_call(
-        functools.partial(_kernel_fused, n_layers=n_layers, d_x=D,
-                          quant=quant, bt=bt),
+        functools.partial(_kernel_fused, n_layers=n_layers, d_x=d_x, bt=bt),
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((qp,), jnp.float32),
-                   jax.ShapeDtypeStruct((qp, D), jnp.float32),
-                   jax.ShapeDtypeStruct((qp, D), jnp.float32)),
+        out_shape=(jax.ShapeDtypeStruct((qp, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((qp, d_x), jnp.float32),
+                   jax.ShapeDtypeStruct((qp, d_x), jnp.float32)),
         interpret=interpret,
-    )(idx, *args)
-    return vals[:Q], grads[:Q], x[:Q]
+        name="mlp_grad_fused",
+    )(idx, rows, query, *wbt)
+    return vals[:Q, 0], grads[:Q], x[:Q]

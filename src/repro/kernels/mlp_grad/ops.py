@@ -71,6 +71,6 @@ def mlp_grad_fused(store: CorpusStore, idx: jax.Array, query: jax.Array,
         "mlp_grad_fused", q=int(idx.shape[0]), m=0, d=int(store.dim),
         dtype=store.dtype, override=autotune.parse_tile(tile))
     return mlp_grad_fused_pallas(
-        store.data, store.scales, idx, query.astype(jnp.float32),
-        *_flat(Ws, bs), *_wt_rows(Ws), n_layers=len(Ws),
+        store.kernel_rows(), idx, query.astype(jnp.float32),
+        *_flat(Ws, bs), *_wt_rows(Ws), n_layers=len(Ws), d_x=store.dim,
         interpret=interpret, bt=cfg.bt)

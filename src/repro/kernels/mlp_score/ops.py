@@ -89,6 +89,6 @@ def mlp_score_fused(store: CorpusStore, idx: jax.Array, query: jax.Array,
     q_shared = query.ndim == 1
     q_arg = query[None, :] if q_shared else query
     return mlp_score_fused_pallas(
-        store.data, store.scales, idx, q_arg.astype(jnp.float32),
-        *_flat(Ws, bs), n_layers=len(Ws), q_shared=q_shared,
+        store.kernel_rows(), idx, q_arg.astype(jnp.float32),
+        *_flat(Ws, bs), n_layers=len(Ws), d_x=store.dim, q_shared=q_shared,
         interpret=interpret, bt=cfg.bt, mask=mask)

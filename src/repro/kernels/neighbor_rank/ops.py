@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.neighbor_rank.kernel import neighbor_rank_pallas
-from repro.kernels.neighbor_rank.ref import neighbor_rank_ref
+from repro.kernels.neighbor_rank.ref import keys_from_raw, neighbor_rank_ref
 
 
 def neighbor_rank(x, grad, nvecs, valid, alpha: float = 1.01,
@@ -22,9 +22,8 @@ def neighbor_rank(x, grad, nvecs, valid, alpha: float = 1.01,
         x = jnp.pad(x, ((0, pad), (0, 0)))
         grad = jnp.pad(grad, ((0, pad), (0, 0)))
         nvecs = jnp.pad(nvecs, ((0, pad), (0, 0), (0, 0)))
-        valid = jnp.pad(valid, ((0, pad), (0, 0)))
-    key, mask = neighbor_rank_pallas(
+    raw = neighbor_rank_pallas(
         x.astype(jnp.float32), grad.astype(jnp.float32),
-        nvecs.astype(jnp.float32), valid,
-        alpha=alpha, rank_by=rank_by, block_q=block_q, interpret=interpret)
-    return key[:Q], (mask[:Q] != 0)
+        nvecs.astype(jnp.float32), rank_by=rank_by, block_q=block_q,
+        interpret=interpret)
+    return keys_from_raw(raw[:Q], valid, alpha, rank_by)

@@ -6,7 +6,9 @@ block staged through HBM before it runs. This variant takes the resident
 corpus plus the (Q, B) neighbor-id table and performs the row gather
 *inside* the kernel — and instead of the original one-(q, b)-pair-per-step
 BlockSpec gather, each grid step now DMAs a tile of ``bt`` neighbor rows
-into a double-buffered (2, bt, D) VMEM scratch (``kernels/dma.py``). The
+of the store's kernel payload (``CorpusStore.kernel_rows``: float32 rows
+padded to 128 lanes) into a double-buffered (2, bt, Dp) VMEM scratch
+(``kernels/dma.py``). The
 (q, neighbor-tile) grid is linearized to 1-D so the double-buffer schedule
 is uniform: step ``t`` covers lane ``t // tiles_per_q``'s neighbors
 ``[bt·(t % tiles_per_q), ...)``, and step ``t+1``'s row copies (which may
@@ -14,11 +16,11 @@ cross a lane boundary — the flat id vector doesn't care) are issued before
 step ``t``'s compute, hiding the gather behind the (bt, D) rank math.
 ``bt`` comes from the autotune cache; B is padded up to a multiple.
 
-Per tile: dequantize the rows (int8: per-row scale tile on the same DMA
-schedule), separation angle (or projection) of x' − x against ∂f/∂x, a
-(bt,) key row out. The α·θ band needs the row-wise best key, which is
-O(Q·B) with no D dimension — ops.py applies it on the kernel output
-(shared with the ref's masking helper).
+Per tile: cosine (or projection) of x' − x against ∂f/∂x, a (bt, 1) raw
+score column out. The angle, the row-wise best θ and the α·θ band are
+O(Q·B) with no D dimension — ops.py takes them on the kernel output
+(``neighbor_rank.ref.keys_from_raw``, shared with the ref; Mosaic lowers
+no ``acos``).
 """
 from __future__ import annotations
 
@@ -29,88 +31,64 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dma import RowGather, schedule_double_buffer
-from repro.kernels.quant import rows_f32
+from repro.kernels.dma import RowGather, gather_scratch
 
 
 def _rank_tile(x, g, rows, *, rank_by: str):
-    """x/g: (D,); rows: (bt, D) -> (bt,) keys."""
+    """x/g: (1, D); rows: (bt, D) -> (bt, 1) raw scores."""
     eps = 1e-12
-    diff = rows - x[None, :]
-    dot = jnp.sum(diff * g[None, :], axis=1)
-    gnorm = jnp.sqrt(jnp.sum(g * g)) + eps
+    diff = rows - x
+    dot = jnp.sum(diff * g, axis=1, keepdims=True)
+    gnorm = jnp.sqrt(jnp.sum(g * g, axis=1, keepdims=True)) + eps
     if rank_by == "angle":
-        dnorm = jnp.sqrt(jnp.sum(diff * diff, axis=1)) + eps
-        cosv = jnp.clip(dot / (dnorm * gnorm), -1.0, 1.0)
-        key = jnp.arccos(cosv)
-    else:
-        key = -(dot / gnorm)
-    return key.astype(jnp.float32)
+        dnorm = jnp.sqrt(jnp.sum(diff * diff, axis=1, keepdims=True)) + eps
+        return jnp.clip(dot / (dnorm * gnorm), -1.0, 1.0)
+    return dot / gnorm
 
 
-def _kernel(idx_ref, *refs, rank_by: str, bt: int, quant: bool):
-    if quant:
-        (x_ref, g_ref, data_ref, scales_ref, key_ref,
-         vmem, svmem, dsem, ssem) = refs
-    else:
-        x_ref, g_ref, data_ref, key_ref, vmem, dsem = refs
-    t = pl.program_id(0)
-    gathers = [RowGather(idx_ref, data_ref, vmem, dsem, bt)]
-    if quant:
-        gathers.append(RowGather(idx_ref, scales_ref, svmem, ssem, bt))
-    slot = schedule_double_buffer(t, gathers)
-    rows = rows_f32(vmem[slot])                           # (bt, D)
-    if quant:
-        rows = rows * svmem[slot]
-    key_ref[0, :] = _rank_tile(x_ref[0, :], g_ref[0, :], rows,
-                               rank_by=rank_by)
+def _kernel(idx_ref, x_ref, g_ref, data_ref, raw_ref, vmem, sem, *,
+            rank_by: str, bt: int, dim: int):
+    rows = RowGather(idx_ref, data_ref, vmem, sem, bt).rows(
+        pl.program_id(0), dim)                            # (bt, D)
+    raw_ref[...] = _rank_tile(x_ref[...], g_ref[...], rows, rank_by=rank_by)
 
 
-@functools.partial(jax.jit, static_argnames=("rank_by", "interpret", "bt"))
-def neighbor_rank_fused_pallas(x, grad, data, scales, idx, *,
+@functools.partial(jax.jit, static_argnames=("dim", "rank_by", "interpret",
+                                             "bt"))
+def neighbor_rank_fused_pallas(x, grad, rows, idx, *, dim: int,
                                rank_by: str = "angle",
                                interpret: bool = False,
                                bt: int = 8) -> jax.Array:
-    """x/grad: (Q, D) f32; data: (N, D) resident corpus (f32/bf16/int8);
-    scales: (N, 1) f32 for int8 data, else None; idx: (Q, B) int32 row ids
-    (must be pre-clamped >= 0); bt: neighbor rows per grid step (autotuned;
-    B is padded up to a multiple). Returns raw keys (Q, B) f32 — validity
-    masking and the α·θ band are applied by ops.py."""
+    """x/grad: (Q, D) f32; rows: (N, Dp) f32 kernel payload
+    (``CorpusStore.kernel_rows``) whose first ``dim`` lanes are the corpus;
+    idx: (Q, B) int32 row ids (must be pre-clamped >= 0); bt: neighbor rows
+    per grid step (autotuned; B is padded up to a multiple). Returns raw
+    scores (Q, B) f32 — ops.py turns them into keys and the α·θ band."""
     Q, B = idx.shape
-    D = data.shape[1]
-    quant = scales is not None
-    bt = max(1, min(int(bt), B))
+    bt = max(1, int(bt))
     bp = -(-B // bt) * bt
     tiles_per_q = bp // bt
     idx_flat = jnp.pad(idx, ((0, 0), (0, bp - B))).reshape(Q * bp)
-    lane = lambda t, idx_ref: (t // tiles_per_q, 0)
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
-    in_specs = [
-        pl.BlockSpec((1, D), lane),                       # x
-        pl.BlockSpec((1, D), lane),                       # grad
-        any_spec,                                         # corpus
-    ]
-    args = [x.astype(jnp.float32), grad.astype(jnp.float32), data]
-    scratch = [pltpu.VMEM((2, bt, D), data.dtype)]
-    if quant:
-        in_specs.append(any_spec)                         # row scales
-        args.append(scales)
-        scratch.append(pltpu.VMEM((2, bt, 1), jnp.float32))
-    scratch.append(pltpu.SemaphoreType.DMA((2, bt)))
-    if quant:
-        scratch.append(pltpu.SemaphoreType.DMA((2, bt)))
+    # x/grad travel as (Q, 1, D): a (1, D) block of a (Q, D) array is off
+    # the (8, 128) tiling, but it is the whole of each (1, D) slab
+    lane = lambda t, idx_ref: (t // tiles_per_q, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(Q * tiles_per_q,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, bt), lambda t, idx_ref: (t // tiles_per_q, t % tiles_per_q)),
-        scratch_shapes=scratch,
+        in_specs=[
+            pl.BlockSpec((None, 1, dim), lane),           # x
+            pl.BlockSpec((None, 1, dim), lane),           # grad
+            pl.BlockSpec(memory_space=pl.ANY),            # corpus
+        ],
+        out_specs=pl.BlockSpec((bt, 1), lambda t, idx_ref: (t, 0)),
+        scratch_shapes=gather_scratch(bt, rows.shape[1]),
     )
-    key = pl.pallas_call(
-        functools.partial(_kernel, rank_by=rank_by, bt=bt, quant=quant),
+    raw = pl.pallas_call(
+        functools.partial(_kernel, rank_by=rank_by, bt=bt, dim=dim),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Q, bp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((Q * bp, 1), jnp.float32),
         interpret=interpret,
-    )(idx_flat, *args)
-    return key[:, :B]
+        name="neighbor_rank_fused",
+    )(idx_flat, x.astype(jnp.float32)[:, None, :],
+      grad.astype(jnp.float32)[:, None, :], rows)
+    return raw.reshape(Q, bp)[:, :B]

@@ -1,5 +1,5 @@
 """Public wrapper for index-fused neighbor ranking: backend pick, id
-clamping, and the shared α·θ masking on the raw kernel keys."""
+clamping, and the shared keys and α·θ band on the raw kernel scores."""
 from __future__ import annotations
 
 import jax
@@ -7,9 +7,9 @@ import jax.numpy as jnp
 
 from repro.core.corpus import CorpusStore
 from repro.kernels import autotune
+from repro.kernels.neighbor_rank.ref import keys_from_raw
 from repro.kernels.neighbor_rank_fused.kernel import neighbor_rank_fused_pallas
-from repro.kernels.neighbor_rank_fused.ref import (mask_from_key,
-                                                   neighbor_rank_fused_ref)
+from repro.kernels.neighbor_rank_fused.ref import neighbor_rank_fused_ref
 
 
 def neighbor_rank_fused(x, grad, store: CorpusStore, idx, valid,
@@ -33,7 +33,7 @@ def neighbor_rank_fused(x, grad, store: CorpusStore, idx, valid,
         "neighbor_rank_fused", q=int(idx.shape[0]), m=int(idx.shape[1]),
         d=int(store.dim), dtype=store.dtype,
         override=autotune.parse_tile(tile))
-    key = neighbor_rank_fused_pallas(
-        x, grad, store.data, store.scales, jnp.maximum(idx, 0).astype(jnp.int32),
-        rank_by=rank_by, interpret=interpret, bt=cfg.bt)
-    return mask_from_key(key, valid, alpha, rank_by)
+    raw = neighbor_rank_fused_pallas(
+        x, grad, store.kernel_rows(), jnp.maximum(idx, 0).astype(jnp.int32),
+        dim=store.dim, rank_by=rank_by, interpret=interpret, bt=cfg.bt)
+    return keys_from_raw(raw, valid, alpha, rank_by)

@@ -12,30 +12,8 @@ gather it replaces).
 """
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
-
 from repro.core.corpus import CorpusStore
 from repro.kernels.neighbor_rank.ref import neighbor_rank_ref
-
-
-def mask_from_key(key: jax.Array, valid: jax.Array, alpha: float,
-                  rank_by: str):
-    """Shared Eq. 3/4 masking: raw per-neighbor keys -> (key, in_range) with
-    the ref conventions (invalid = +inf key; adaptive α·θ band)."""
-    eps = 1e-12
-    if rank_by == "angle":
-        key = jnp.where(valid, key, jnp.inf)
-        theta = jnp.min(key, axis=1, keepdims=True)
-        in_range = valid & (key <= alpha * theta + eps)
-    else:
-        proj = -key                        # projection keys are negated
-        pk = jnp.where(valid, proj, -jnp.inf)
-        theta = jnp.max(pk, axis=1, keepdims=True)
-        bound = jnp.where(theta >= 0, theta / alpha, theta * alpha)
-        in_range = valid & (pk >= bound - eps)
-        key = jnp.where(valid, key, jnp.inf)
-    return key.astype(jnp.float32), in_range
 
 
 def neighbor_rank_fused_ref(x, grad, store: CorpusStore, idx, valid,

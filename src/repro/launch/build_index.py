@@ -27,6 +27,7 @@ from repro.core.begin import build_begin_graph
 from repro.core.measures import MEASURE_FAMILIES, make_family_measure
 from repro.core.sharded import build_sharded_index
 from repro.graph import build_l2_graph, save_index
+from repro.utils import enable_compile_cache
 
 
 def main(argv: Optional[Sequence[str]] = None) -> str:
@@ -77,6 +78,7 @@ def main(argv: Optional[Sequence[str]] = None) -> str:
     ap.add_argument("--out", type=str, required=True,
                     help="output index directory")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.base:
         base = np.load(args.base).astype(np.float32)

@@ -45,6 +45,7 @@ from repro.graph import (GraphIndex, build_l2_graph, load_corpus_store,
 from repro.serving import (BATCH_BUCKETS, ContinuousRuntime, Request,  # noqa: F401  (re-export compat)
                            bucket_pad, bucket_size, latency_summary,
                            load_policy, poisson_arrivals)
+from repro.utils import enable_compile_cache
 
 
 def serve_oneshot(args, graph, measure, cfg, options, corpus_arg, nbrs_j,
@@ -224,13 +225,18 @@ def serve_continuous(args, graph, measure, cfg, options, corpus_arg, nbrs_j,
     ok_rids = [i for i in range(nr) if by_rid[i].status == "ok"]
     if not ok_rids:
         # everything in the recall probe window was shed / failed / timed
-        # out — report SLA metrics only instead of dividing by nothing
+        # out — report SLA metrics only instead of dividing by nothing.
+        # Only a run that injected faults on purpose may end that way and
+        # still succeed.
         print(f"[serve] runtime=continuous lanes={args.lanes} "
               f"offered={args.offered_qps:.0f} QPS — no ok completions in "
               f"the recall window (degraded run)")
         print(runtime.format_health())
         print(runtime.metrics.report())
         export_telemetry()
+        if not args.chaos:
+            raise SystemExit("[serve] no request in the recall window "
+                             "completed ok, and no --chaos plan was given")
         return
     true_ids, _ = brute_force_topk(measure, base_j,
                                    jnp.asarray(queries[:nr]), args.k)
@@ -380,6 +386,7 @@ def main() -> None:
     ap.add_argument("--cache-mb", type=int, default=64,
                     help="paged residency: LRU page-cache byte budget (MiB)")
     args = ap.parse_args()
+    cache_dir = enable_compile_cache()
 
     if args.list_measures:
         print("measure-kernel bundle registry "
@@ -397,6 +404,9 @@ def main() -> None:
               "tiles in-kernel; generic fallbacks mask densely")
         return
 
+    dev = jax.devices()[0]
+    print(f"[serve] device: {dev.platform} {dev.device_kind} "
+          f"x{len(jax.devices())}; compile cache {cache_dir}")
     fused = args.fused or args.corpus_dtype != "float32"
     if args.searcher == "legacy" and fused:
         raise SystemExit("--searcher legacy has no index-fused/quantized "
@@ -511,8 +521,11 @@ def main() -> None:
                                   residency=paged_policy)
     if store is None and fused:
         # quantize once, up front — every batch then searches the resident
-        # (possibly bf16/int8) payload without per-call conversion
+        # (possibly bf16/int8) payload, in the engine's layout, without
+        # per-call conversion
         store = make_corpus_store(base_j, args.corpus_dtype)
+    if store is not None and not getattr(store, "is_paged", False):
+        store = build_engine(measure, cfg, options).prepare_store(store)
     corpus_arg = store if store is not None else base_j
     if store is not None and getattr(store, "is_paged", False):
         print(f"[serve] corpus paged: dtype={store.dtype} page_rows="

@@ -27,7 +27,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.sharding import ShardingRules, constrain, single_device_rules
-from repro.utils import shard_map_compat
 
 
 EXPERT_PAD = 16  # expert count padded to a multiple of the TP axis
@@ -219,10 +218,10 @@ def moe_ffn_ep(p: dict, x: jax.Array, *, n_experts: int, top_k: int,
     shared = ((p["shared_gate"], p["shared_up"], p["shared_down"])
               if has_shared else (jnp.zeros((0,)),) * 3)
     shared_specs = tuple(P(*(None,) * a.ndim) for a in shared)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(repl, e_spec, e_spec, e_spec, shared_specs, x_spec),
-        out_specs=x_spec, check=False)
+        out_specs=x_spec, check_vma=False)
     return fn(p["router"], padE(p["w_gate"]), padE(p["w_up"]),
               padE(p["w_down"]), shared, x)
 

@@ -12,8 +12,9 @@ Two layers, both free when unused:
   instead (trace-time metadata, zero runtime cost) — see
   core/engine.py.
 
-Both degrade to null contexts when the profiler is unavailable, so
-nothing here can take the serving path down.
+A trace that was asked for and cannot start raises: a run that was meant
+to be measured must not pass for one that was. :func:`annotate` still
+degrades to a null context, since it only names spans.
 """
 from __future__ import annotations
 
@@ -24,21 +25,13 @@ from typing import Optional
 @contextlib.contextmanager
 def profile_trace(profile_dir: Optional[str]):
     """Capture a jax profiler trace into ``profile_dir`` (viewable with
-    TensorBoard / Perfetto). ``None`` disables; a profiler start
-    failure degrades to a warning, never an exception."""
+    TensorBoard / Perfetto). ``None`` disables; a profiler that cannot
+    start raises."""
     if not profile_dir:
         yield
         return
     import jax
-    try:
-        cm = jax.profiler.trace(profile_dir)
-    except Exception as e:  # noqa: BLE001 - profiler backend optional
-        import warnings
-        warnings.warn(f"jax profiler unavailable ({e!r}); "
-                      "continuing without --profile-dir capture")
-        yield
-        return
-    with cm:
+    with jax.profiler.trace(profile_dir):
         yield
 
 

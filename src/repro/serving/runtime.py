@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.corpus import as_corpus_store
 from repro.core.engine import ExpansionEngine, _freeze_done
 from repro.obs.profile import annotate
 from repro.obs.trace import NULL_TRACER
@@ -124,7 +123,7 @@ class ContinuousRuntime:
                 f"steps_per_tick must be >= 1, got {steps_per_tick}")
         self.engine = engine
         self.params = params
-        self.store = as_corpus_store(corpus, engine.corpus_dtype)
+        self.store = engine.prepare_store(corpus)
         self.neighbors = jnp.asarray(neighbors)
         self.n_lanes = n_lanes
         self.default_entry = entry
@@ -335,7 +334,7 @@ class ContinuousRuntime:
             return False
         corpus, neighbors, entry = self._pending_index
         self._pending_index = None
-        self.store = as_corpus_store(corpus, self.engine.corpus_dtype)
+        self.store = self.engine.prepare_store(corpus)
         self.neighbors = jnp.asarray(neighbors)
         if entry is not None:
             self.default_entry = int(entry)

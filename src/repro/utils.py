@@ -1,6 +1,8 @@
-"""Small shared utilities: PRNG handling, tree helpers, timing."""
+"""Small shared utilities: PRNG handling, tree helpers, timing, the
+persistent compilation cache."""
 from __future__ import annotations
 
+import os
 import time
 from typing import Any, Callable, Iterator
 
@@ -9,16 +11,22 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across JAX versions: the top-level binding (with
-    ``check_vma``) appeared after 0.4.x; older releases only ship
-    ``jax.experimental.shard_map`` (with ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check)
+REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
+                                         ".."))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory. ``JAX_COMPILATION_CACHE_DIR``, when set, is used
+    as is — JAX reads it itself and nothing is set here. Otherwise the
+    cache lives at ``<repo>/.jax_cache``: a fixed path, because the path
+    is part of what a later process must find again."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def key_iter(seed: int) -> Iterator[jax.Array]:

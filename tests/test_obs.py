@@ -486,3 +486,20 @@ def test_degraded_run_trace_attributes_latency(system):
     txt = format_trace(tr, rid, sites=("pager",))
     assert txt.startswith(f"request rid={rid}")
     assert "merge" in txt and "@shard:" in txt
+
+
+def test_profile_trace_raises_when_profiler_cannot_start(monkeypatch,
+                                                         tmp_path):
+    """A trace that was asked for and cannot start fails the run; no
+    directory stays a no-op."""
+    from repro.obs import profile_trace
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "trace", broken)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        with profile_trace(str(tmp_path)):
+            pass
+    with profile_trace(None):
+        pass
