@@ -75,6 +75,5 @@ class RowGather:
 def gather_scratch(bt: int, width: int):
     """The scratch shapes one ``RowGather`` needs: the (2, bt, width) f32
     double buffer and its (2, bt) DMA semaphores."""
-    import jax.numpy as jnp
     return [pltpu.VMEM((2, bt, width), jnp.float32),
             pltpu.SemaphoreType.DMA((2, bt))]
