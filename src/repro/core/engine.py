@@ -209,6 +209,19 @@ def bit_set_rows(bitmap: jax.Array, ids: jax.Array, mask: jax.Array) -> jax.Arra
     return bitmap.at[rows, word].add(updates, mode="drop")
 
 
+def _scoped(name: str):
+    """Trace the decorated function under ``jax.named_scope(name)``: its
+    ops carry the scope in their HLO ``op_name`` metadata, which
+    ``obs.profile.stage_map`` reads back per compiled instruction."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
 def _freeze_done(done: jax.Array, new: Any, old: Any) -> Any:
     """Keep converged lanes' state frozen (lane-granular early exit).
 
@@ -222,7 +235,8 @@ def _freeze_done(done: jax.Array, new: Any, old: Any) -> Any:
     def pick(n, o):
         d = done.reshape((-1,) + (1,) * (n.ndim - 1))
         return jnp.where(d, o, n)
-    frozen = jax.tree_util.tree_map(pick, new, old)
+    with jax.named_scope("repro_loop"):
+        frozen = jax.tree_util.tree_map(pick, new, old)
     return frozen._replace(visited=new.visited)
 
 
@@ -511,6 +525,7 @@ class ExpansionEngine:
     # -- state init: seed pools with the entry points (one measure call).
     #    iter_caps: optional (Q,) per-lane expansion budgets (defaults to
     #    cfg.iters() — the pre-existing uniform cap).
+    @_scoped("repro_init")
     def init_state(self, params, store: CorpusStore, neighbors, queries,
                    entries, iter_caps=None, taus=None) -> EngineState:
         Q = queries.shape[0]
@@ -565,6 +580,7 @@ class ExpansionEngine:
     #    every other lane's state passes through untouched. Idle lanes are
     #    parked with ``done=True`` (``idle_state``): pop sees active=False,
     #    so they cost no measure evaluations and stay frozen.
+    @_scoped("repro_init")
     def reset_lanes(self, params, store: CorpusStore, queries, entries,
                     state: EngineState, mask: jax.Array,
                     iter_caps=None, taus=None) -> EngineState:
@@ -632,20 +648,27 @@ class ExpansionEngine:
             override=autotune.parse_tile(self.tile))
         return cfg_t.plan == "tile"
 
+    # -- the (Q·C, Dq) repeated query block the measure stage scores
+    #    against, hoisted out of the loop because C is static
+    @_scoped("repro_init")
+    def repeat_queries(self, queries, max_degree: int) -> jax.Array:
+        return jnp.repeat(queries, self.n_candidates(max_degree), axis=0)
+
     # -- one iteration over the whole batch: pop → grad → rank → measure →
-    #    insert. qs_flat is the (Q·C, Dq) repeated query block, hoisted out
-    #    of the loop because C is static. The fused variants hand (store,
-    #    idx) to the stages — neighbor/candidate rows are gathered (and
-    #    dequantized) inside them, never staged by the engine — unless the
-    #    tuned plan is ``tile``, which gathers the whole step's rows ONCE
+    #    insert. qs_flat is ``repeat_queries``' block. The fused variants
+    #    hand (store, idx) to the stages — neighbor/candidate rows are
+    #    gathered (and dequantized) inside them, never staged by the
+    #    engine — unless the tuned plan is ``tile``, which gathers the
+    #    whole step's rows ONCE
     #    (frontier + neighbors, dequant included) into a (Q, 1+B, D) tile
     #    pinned by ``optimization_barrier`` and feeds every pre-gathered
     #    stage from slices of it.
     def step(self, params, store: CorpusStore, neighbors, queries, qs_flat,
              state: EngineState) -> EngineState:
-        # jax.named_scope labels the HLO per stage (visible in --profile-dir
-        # captures and compiled dumps); trace-time metadata only — the
-        # emitted program and its numerics are bit-identical
+        # jax.named_scope labels the HLO per stage (``compiled_text`` +
+        # ``obs.profile.stage_map`` join it to a device trace); trace-time
+        # metadata only — the emitted program and its numerics are
+        # bit-identical
         Q = queries.shape[0]
         with jax.named_scope("repro_pop"):
             s, pop = self.pop(state)
@@ -745,6 +768,7 @@ class ExpansionEngine:
                 | ~pop.active
         return s._replace(done=done)
 
+    @_scoped("repro_init")
     def _result(self, final: EngineState) -> SearchResult:
         k = self.cfg.k
         return SearchResult(ids=final.pool_ids[:, :k],
@@ -752,25 +776,51 @@ class ExpansionEngine:
                             n_eval=final.n_eval, n_grad=final.n_grad,
                             n_iters=final.n_iters)
 
-    # -- jitted whole-search path (serving / benchmarks)
+    # -- jitted whole-search path (serving / benchmarks). Stage scopes:
+    #    repro_init (store, seed pools, query block, result), the five step
+    #    stages, and repro_loop (the loop's condition and _freeze_done)
     @functools.cached_property
     def _run_jit(self):
         def run(params, base, neighbors, queries, entries, iter_caps, taus):
-            store = self.prepare_store(base)
+            with jax.named_scope("repro_init"):
+                store = self.prepare_store(base)
             state = self.init_state(params, store, neighbors, queries,
                                     entries, iter_caps, taus)
-            C = self.n_candidates(neighbors.shape[1])
-            qs_flat = jnp.repeat(queries, C, axis=0)
+            qs_flat = self.repeat_queries(queries, neighbors.shape[1])
 
             def cond(s):
-                return ~jnp.all(s.done)
+                with jax.named_scope("repro_loop"):
+                    return ~jnp.all(s.done)
 
             def body(s):
                 s2 = self.step(params, store, neighbors, queries, qs_flat, s)
                 return _freeze_done(s.done, s2, s)
 
-            return self._result(jax.lax.while_loop(cond, body, state))
+            with jax.named_scope("repro_loop"):
+                final = jax.lax.while_loop(cond, body, state)
+            return self._result(final)
         return jax.jit(run)
+
+    def _lane_args(self, queries, iter_caps, taus) -> tuple:
+        """``search``'s per-lane caps and cutoffs, defaults filled in."""
+        if iter_caps is None:
+            iter_caps = jnp.full((queries.shape[0],), self.cfg.iters(),
+                                 jnp.int32)
+        if taus is None:
+            taus = jnp.full((queries.shape[0],), self.angle_tau, jnp.float32)
+        return iter_caps, taus
+
+    def compiled_text(self, params, base, neighbors, queries, entries,
+                      iter_caps=None, taus=None) -> str:
+        """The compiled program ``search`` runs for these arguments, as
+        HLO text; each instruction's ``op_name`` names its stage scope
+        (``obs.profile.stage_map``). Arguments as for ``search``, or
+        ``jax.ShapeDtypeStruct``s (pass both per-lane arguments then).
+        Compiles, or reads JAX's compile caches."""
+        iter_caps, taus = self._lane_args(queries, iter_caps, taus)
+        return self._run_jit.lower(params, base, neighbors, queries,
+                                   entries, iter_caps,
+                                   taus).compile().as_text()
 
     def search(self, params, base, neighbors, queries, entries,
                iter_caps=None, taus=None) -> SearchResult:
@@ -782,11 +832,7 @@ class ExpansionEngine:
         uniform cfg cap); taus: optional (Q,) per-query adaptive angle
         cutoffs (adaptive='angle' only — defaults to the engine's
         ``angle_tau``). Returns SearchResult with (Q, ...) leaves."""
-        if iter_caps is None:
-            iter_caps = jnp.full((queries.shape[0],), self.cfg.iters(),
-                                 jnp.int32)
-        if taus is None:
-            taus = jnp.full((queries.shape[0],), self.angle_tau, jnp.float32)
+        iter_caps, taus = self._lane_args(queries, iter_caps, taus)
         from repro.obs.profile import annotate
         with annotate("repro/search"):
             return self._run_jit(params, base, neighbors, queries, entries,
@@ -822,22 +868,17 @@ class ExpansionEngine:
         store = self.prepare_store(base)
         if jit_steps:
             init_fn, step_fn = self._debug_jits
-            caps = jnp.full((queries.shape[0],), self.cfg.iters(),
-                            jnp.int32) if iter_caps is None \
-                else jnp.asarray(iter_caps, jnp.int32)
-            ts = jnp.full((queries.shape[0],), self.angle_tau,
-                          jnp.float32) if taus is None \
-                else jnp.asarray(taus, jnp.float32)
+            caps, ts = self._lane_args(queries, iter_caps, taus)
             state = init_fn(params, store, neighbors, queries, entries,
-                            caps, ts)
+                            jnp.asarray(caps, jnp.int32),
+                            jnp.asarray(ts, jnp.float32))
         else:
             def step_fn(params, store, neighbors, queries, qs_flat, s):
                 s2 = self.step(params, store, neighbors, queries, qs_flat, s)
                 return _freeze_done(s.done, s2, s)
             state = self.init_state(params, store, neighbors, queries,
                                     entries, iter_caps, taus)
-        C = self.n_candidates(neighbors.shape[1])
-        qs_flat = jnp.repeat(queries, C, axis=0)
+        qs_flat = self.repeat_queries(queries, neighbors.shape[1])
         if max_steps is not None:
             limit = max_steps
         else:

@@ -208,7 +208,8 @@ def serve_continuous(args, graph, measure, cfg, options, corpus_arg, nbrs_j,
                        key=lambda c: c.record.latency_ms, default=None)
             if slow is not None:
                 print(f"[serve] slowest traced ok request:")
-                print(format_trace(tracer, slow.rid, sites=("pager",)))
+                print(format_trace(tracer, slow.rid,
+                                   sites=("pager", runtime.site)))
         if registry is not None:
             with open(args.metrics_out, "w") as f:
                 f.write(registry.render_text())
@@ -347,7 +348,9 @@ def main() -> None:
                          "(machine-readable twin of the [serve] report)")
     ap.add_argument("--profile-dir", type=str, default=None,
                     help="capture a jax profiler trace of the whole serve "
-                         "run into this directory (TensorBoard/Perfetto)")
+                         "run into this directory (TensorBoard/Perfetto), "
+                         "with its clock anchor (repro_clock.json) that "
+                         "maps --trace-out spans onto the trace's clock")
     ap.add_argument("--k", type=int, default=10)
     ap.add_argument("--ef", type=int, default=64)
     ap.add_argument("--alpha", type=float, default=1.01)

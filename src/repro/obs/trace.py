@@ -1,12 +1,15 @@
 """Per-request tracing over monotonic host clocks.
 
 A :class:`Tracer` collects :class:`Span` records into a bounded ring
-buffer. Spans form trees: each request gets a root ``request`` span
-(created at submit, finished at completion) and the serving runtime
-emits phase spans (``queue``, ``admit``, ``tick``, ``harvest``,
-``merge``) parented to it. Subsystems without a request identity (the
-pager, the mutation journal) emit site-scoped spans (``site="pager"`` /
-``site="mutate"``) that overlap the request windows temporally.
+buffer. Spans form trees: each sampled request gets a root ``request``
+span (created at submit, finished at completion) with its ``queue`` span
+(submit to admit) and, under the sharded runtime, its ``merge`` span
+parented to it. Work without a request identity is site-scoped
+(``rid=None``): the serving runtime's ``round`` spans (one per scheduler
+round, ``site="runtime"`` or ``"shard:<s>"``, with ``admit`` /
+``dispatch`` / ``fetch`` / ``resolve`` children), the pager's
+(``site="pager"``) and the mutation journal's (``site="mutate"``). They
+overlap the request windows in time, and ``sites=`` weaves them in.
 
 Two properties the rest of the stack relies on:
 
@@ -17,11 +20,11 @@ Two properties the rest of the stack relies on:
   so independent emitters (per-shard sub-runtimes, the sharded merge
   layer) agree on which requests are traced without coordination.
 
-The phase spans tile each scheduler round with shared timestamps, so
-the union of a request's leaf intervals covers its wall-clock up to the
-inter-round Python gaps — :func:`attribution` computes that union and
-the per-phase breakdown; the acceptance bar is >=95% coverage even on a
-degraded (shard-crash + pager-fault) run.
+A round's phase spans tile it with shared timestamps, so the union of a
+request's ``queue`` span and the rounds it was in flight covers its
+wall-clock up to the inter-round Python gaps — :func:`attribution`
+computes that union and the per-phase breakdown; the acceptance bar is
+>=95% coverage even on a degraded (shard-crash + pager-fault) run.
 """
 from __future__ import annotations
 

@@ -34,7 +34,7 @@ import collections
 import dataclasses
 import itertools
 import time
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -159,6 +159,7 @@ class ContinuousRuntime:
         self._trace_owner = trace_owner
         self._queue_spans: Dict[int, int] = {}
         self._n_ticks = 0
+        self._t_fetched: Optional[float] = None
 
         self.epoch = 0
         self._pending_index: Optional[tuple] = None
@@ -192,14 +193,14 @@ class ContinuousRuntime:
                                    mask, caps, taus)
 
         def tick(params, store, neighbors, queries, state):
-            C = eng.n_candidates(neighbors.shape[1])
-            qs_flat = jnp.repeat(queries, C, axis=0)
+            qs_flat = eng.repeat_queries(queries, neighbors.shape[1])
 
             def body(_, s):
                 s2 = eng.step(params, store, neighbors, queries, qs_flat, s)
                 return _freeze_done(s.done, s2, s)
 
-            return jax.lax.fori_loop(0, spt, body, state)
+            with jax.named_scope("repro_loop"):
+                return jax.lax.fori_loop(0, spt, body, state)
 
         self._reset_fn = jax.jit(reset)
         self._tick_fn = jax.jit(tick)
@@ -347,15 +348,17 @@ class ContinuousRuntime:
 
     # -- scheduler round ----------------------------------------------------
 
-    def _admit(self, now: float) -> List[Completion]:
+    def _admit(self, now: float) -> Tuple[List[Completion], np.ndarray]:
+        """Move queued requests into free lanes (host bookkeeping only);
+        returns the deadline drops and the mask of lanes to reset."""
         dropped: List[Completion] = []
+        mask = np.zeros((self.n_lanes,), bool)
         if self._pending_index is not None:
-            return dropped      # admissions hold until the staged epoch
+            return dropped, mask    # admissions hold until the staged epoch
         free = [l for l in range(self.n_lanes) if self._lane_req[l] is None]
         if not free or not self.queue:
-            return dropped
+            return dropped, mask
         tr = self.tracer
-        mask = np.zeros((self.n_lanes,), bool)
         while free and self.queue:
             req = self.queue.popleft()
             if req.deadline is not None and now - req.t_arrive > req.deadline:
@@ -412,16 +415,20 @@ class ContinuousRuntime:
                                    else self.engine.cfg.iters())
             self._taus_np[lane] = (tau if tau is not None
                                    else self.engine.angle_tau)
-        if not mask.any():
-            return dropped
-        self._queries_j = jnp.asarray(self._queries_np)
-        with annotate("repro/reset"):
-            self._state = self._reset_fn(
-                self.params, self.store, self._queries_j,
-                jnp.asarray(self._entries_np), self._state,
-                jnp.asarray(mask), jnp.asarray(self._caps_np),
-                jnp.asarray(self._taus_np))
-        return dropped
+        return dropped, mask
+
+    def _dispatch(self, mask: np.ndarray) -> None:
+        """Send the round's device work: the lane-masked reset of the
+        newly admitted lanes, then the tick. Both calls are async."""
+        if mask.any():
+            self._queries_j = jnp.asarray(self._queries_np)
+            with annotate("repro/reset"):
+                self._state = self._reset_fn(
+                    self.params, self.store, self._queries_j,
+                    jnp.asarray(self._entries_np), self._state,
+                    jnp.asarray(mask), jnp.asarray(self._caps_np),
+                    jnp.asarray(self._taus_np))
+        self._tick()
 
     def _tick(self) -> None:
         self.tick_penalty_s = 0.0
@@ -454,6 +461,10 @@ class ContinuousRuntime:
             (self._state.done, self._state.pool_ids[:, :k],
              self._state.pool_scores[:, :k], self._state.n_eval,
              self._state.n_grad, self._state.n_iters))
+        if self.tracer.enabled:
+            # the traced round's fetch | resolve edge, stamped here so the
+            # round still goes through this one method
+            self._t_fetched = self._now()
         ready = [l for l in occupied if done[l]]
         if not ready:
             return []
@@ -486,48 +497,54 @@ class ContinuousRuntime:
         once the previous epoch's lanes have all harvested."""
         self._maybe_swap_index()
         self.metrics.observe_queue_depth(len(self.queue))
-        tr = self.tracer
-        if not tr.enabled:
-            dropped = self._admit(self._now())
-            self._tick()
+        if not self.tracer.enabled:
+            dropped, mask = self._admit(self._now())
+            self._dispatch(mask)
             return dropped + self._harvest(self._now())
-        # traced round: the four shared timestamps tile the round so the
-        # per-request phase spans (admit/tick/harvest) union to the round's
-        # wall-clock — attribution coverage comes from this tiling. NOTE
-        # the tick dispatch is async: on-device compute drains at the
-        # harvest fetch's sync, so "harvest" carries the compute wait
-        # (documented in DESIGN.md §13).
+        # traced round: five shared timestamps tile it into admit (host
+        # bookkeeping), dispatch (the async reset + tick calls), fetch (the
+        # one device_get, which waits out the dispatched device work) and
+        # resolve (the per-lane Python after it)
         t0 = self._now()
-        dropped = self._admit(t0)
+        dropped, mask = self._admit(t0)
         t1 = self._now()
-        self._tick()
+        self._dispatch(mask)
         t2 = self._now()
+        self._t_fetched = None
         harvested = self._harvest(t2)
-        t3 = self._now()
-        self._emit_round_spans(t0, t1, t2, t3, harvested)
+        t4 = self._now()
+        t3 = t2 if self._t_fetched is None else self._t_fetched
+        self._emit_round_spans((t0, t1, t2, t3, t4), int(mask.sum()),
+                               dropped, harvested)
         return dropped + harvested
 
-    def _emit_round_spans(self, t0: float, t1: float, t2: float, t3: float,
+    @property
+    def site(self) -> str:
+        """The site of this runtime's round spans: its ``trace_site``, or
+        ``"runtime"`` for a standalone runtime. ``attribution`` and
+        ``format_trace`` weave them into a request through ``sites``."""
+        return self.trace_site or "runtime"
+
+    def _emit_round_spans(self, ts: tuple, admitted: int,
+                          dropped: List[Completion],
                           harvested: List[Completion]) -> None:
+        """One ``round`` span with its four phases, at the runtime's
+        site (no rid): the same set whatever the number of lanes. A round
+        with no lane busy and nothing admitted or dropped emits nothing."""
         tr = self.tracer
-        rids = [r.rid for r in self._lane_req
-                if r is not None and tr.sampled(r.rid)]
-        rids += [c.rid for c in harvested
-                 if c.lane >= 0 and tr.sampled(c.rid)]
-        site = self.trace_site
-        for rid in rids:
-            root = tr.root_for(rid)
-            if t1 > t0:
-                tr.emit("admit", t0, t1, rid=rid, site=site, parent=root)
-            if t2 > t1:
-                tr.emit("tick", t1, t2, rid=rid, site=site, parent=root,
-                        i=self._n_ticks, steps=self.steps_per_tick)
-            if t3 > t2:
-                tr.emit("harvest", t2, t3, rid=rid, site=site, parent=root)
+        busy = self.in_flight + len(harvested)
+        if busy or dropped:
+            site = self.site
+            rnd = tr.emit("round", ts[0], ts[4], site=site, i=self._n_ticks,
+                          steps=self.steps_per_tick, lanes=busy,
+                          admitted=admitted, resolved=len(harvested))
+            for name, a, b in zip(("admit", "dispatch", "fetch", "resolve"),
+                                  ts, ts[1:]):
+                tr.emit(name, a, b, site=site, parent=rnd)
         if self._trace_owner:
             for c in harvested:
                 if c.lane >= 0 and tr.sampled(c.rid):
-                    tr.finish_request(c.rid, t1=t3, status=c.status)
+                    tr.finish_request(c.rid, t1=ts[4], status=c.status)
 
     def close(self) -> List[Completion]:
         """Graceful drain: stop admitting (late submits are shed), shed the

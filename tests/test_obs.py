@@ -354,10 +354,13 @@ def test_single_runtime_bit_identical_traced_vs_untraced(system):
         np.testing.assert_array_equal(got[rid].ids, ref[rid].ids)
         np.testing.assert_array_equal(got[rid].scores, ref[rid].scores)
         assert got[rid].status == ref[rid].status
-    # every request produced a closed root + phase spans
+    # every request produced a closed root + queue span; the phases are
+    # the runtime's round spans
     for rid in ref:
         names = {s.name for s in tr.spans(rid=rid)}
-        assert {"request", "queue", "tick", "harvest"} <= names
+        assert {"request", "queue"} <= names
+    rounds = {s.name for s in tr.spans(rid=None, site="runtime")}
+    assert rounds == {"round", "admit", "dispatch", "fetch", "resolve"}
 
 
 def test_paged_continuous_bit_identical_traced(system):
@@ -408,10 +411,10 @@ def test_sampled_out_requests_emit_zero_spans(system):
 def test_healthy_run_attribution_covers_wall_clock(system):
     tr = Tracer(sample=1)
     _run_single(system, tracer=tr)
-    att = attribution(tr.spans(), 0)
+    att = attribution(tr.spans(), 0, sites=("runtime",))
     assert att["wall_ms"] > 0
     assert att["coverage"] >= 0.95
-    assert {"queue", "tick", "harvest"} <= set(att["by_name"])
+    assert {"queue", "round", "dispatch", "fetch"} <= set(att["by_name"])
 
 
 def test_runtime_bind_registry_exposes_serving_series(system):
@@ -438,6 +441,9 @@ def test_runtime_bind_registry_exposes_serving_series(system):
 # ---------------------------------------------------------------------------
 # the acceptance bar: traced degraded run attributes the wall-clock
 # ---------------------------------------------------------------------------
+
+SHARD_SITES = ("pager", "shard:0", "shard:1")
+
 
 def test_degraded_run_trace_attributes_latency(system):
     """Chaos plan (one shard's ticks crash until its breaker opens) plus
@@ -474,7 +480,7 @@ def test_degraded_run_trace_attributes_latency(system):
     for rid, c in got.items():
         if rid % 2 or c.status not in ("ok", "partial"):
             continue
-        att = attribution(spans, rid, sites=("pager",))
+        att = attribution(spans, rid, sites=SHARD_SITES)
         assert att["wall_ms"] > 0
         assert att["coverage"] >= 0.95, \
             f"rid {rid} ({c.status}): coverage {att['coverage']:.3f}"
@@ -483,9 +489,9 @@ def test_degraded_run_trace_attributes_latency(system):
     # a degraded request's flame renders with its merge + phase spans
     rid = next(r for r, c in got.items()
                if r % 2 == 0 and c.status == "partial")
-    txt = format_trace(tr, rid, sites=("pager",))
+    txt = format_trace(tr, rid, sites=SHARD_SITES)
     assert txt.startswith(f"request rid={rid}")
-    assert "merge" in txt and "@shard:" in txt
+    assert "merge" in txt and "round @shard:" in txt
 
 
 def test_profile_trace_raises_when_profiler_cannot_start(monkeypatch,
@@ -503,3 +509,115 @@ def test_profile_trace_raises_when_profiler_cannot_start(monkeypatch,
             pass
     with profile_trace(None):
         pass
+
+
+# ---------------------------------------------------------------------------
+# the runtime's round spans
+# ---------------------------------------------------------------------------
+
+PHASES = ("admit", "dispatch", "fetch", "resolve")
+
+
+def _rounds(tr, site="runtime"):
+    """[(round span, its phase spans as emitted)] at one site."""
+    spans = tr.spans(rid=None, site=site)
+    kids = {}
+    for sp in spans:
+        if sp.name in PHASES:
+            kids.setdefault(sp.parent_id, []).append(sp)
+    return [(sp, kids.get(sp.span_id, [])) for sp in spans
+            if sp.name == "round"]
+
+
+@pytest.mark.parametrize("lanes", [1, 4, 16])
+def test_round_spans_one_set_per_round_whatever_the_lanes(system, lanes):
+    tr = Tracer(sample=1)
+    rt = ContinuousRuntime(system["engine"], system["measure"].params,
+                           system["base"], system["graph"].neighbors,
+                           n_lanes=lanes, query_dim=16,
+                           entry=system["graph"].entry, steps_per_tick=2,
+                           tracer=tr)
+    for i in range(16):
+        rt.submit(system["queries"][i], rid=i)
+    n_rounds = 0
+    while rt.queue or rt.in_flight:
+        rt.step_once()
+        n_rounds += 1
+    rounds = _rounds(tr)
+    assert len(rounds) == n_rounds
+    for rnd, phases in rounds:
+        assert [p.name for p in phases] == list(PHASES)
+    # site spans are the rounds' and nothing else; requests keep only
+    # their root and queue spans
+    assert len(tr.spans(rid=None)) == 5 * n_rounds
+    assert {s.name for s in tr.spans() if s.rid is not None} == {
+        "request", "queue"}
+
+
+def test_round_spans_tile_the_round(system):
+    tr = Tracer(sample=1)
+    _run_single(system, tracer=tr)
+    rounds = _rounds(tr)
+    assert rounds
+    for rnd, (adm, disp, fetch, res) in rounds:
+        edges = [(rnd.t0, adm.t0), (adm.t1, disp.t0), (disp.t1, fetch.t0),
+                 (fetch.t1, res.t0), (res.t1, rnd.t1)]
+        for a, b in edges:
+            assert abs(a - b) <= 1e-6
+        assert all(p.t1 >= p.t0 for p in (adm, disp, fetch, res))
+        assert rnd.attrs["lanes"] >= 1
+
+
+def test_round_spans_fit_the_ring_at_64_lanes(system):
+    """200 rounds of 64 busy lanes, every request traced: the default
+    ring holds every span (per-lane phase copies would need ~38,000)."""
+    tr = Tracer(sample=1)
+    rt = ContinuousRuntime(system["engine"], system["measure"].params,
+                           system["base"], system["graph"].neighbors,
+                           n_lanes=64, query_dim=16,
+                           entry=system["graph"].entry, steps_per_tick=1,
+                           tracer=tr)
+    qs = np.random.default_rng(7).normal(size=(2000, 16)).astype(np.float32)
+    for i, q in enumerate(qs):
+        rt.submit(q, rid=i)
+    for _ in range(200):
+        rt.step_once()
+    assert rt.queue                         # every round ran 64 busy lanes
+    rounds = _rounds(tr)
+    assert len(rounds) == 200
+    assert all(r.attrs["lanes"] == 64 for r, _ in rounds)
+    assert tr.n_emitted == len(tr.spans()) < tr.capacity
+
+
+def test_clock_anchor_maps_spans_into_their_annotation(tmp_path):
+    """profile_trace writes the session's anchor beside a CPU trace; a
+    span emitted inside a TraceAnnotation maps, through the anchor, to
+    inside that annotation."""
+    import glob
+    import time
+
+    from jax.profiler import ProfileData
+
+    from repro.obs.profile import ANCHOR_FILE, profile_trace, trace_clock
+    tr = Tracer()
+    with profile_trace(str(tmp_path)):
+        time.sleep(0.01)
+        with jax.profiler.TraceAnnotation("test/outer"):
+            time.sleep(0.002)
+            t0 = time.perf_counter()
+            time.sleep(0.005)
+            tr.emit("inner", t0, time.perf_counter())
+            time.sleep(0.002)
+    with open(tmp_path / ANCHOR_FILE) as f:
+        anchor = json.load(f)["perf_counter"]
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    profile = ProfileData.from_file(path)
+    outer = [ev for plane in profile.planes for line in plane.lines
+             for ev in line.events if ev.name == "test/outer"]
+    assert len(outer) == 1
+    a0, a1 = outer[0].start_ns, outer[0].start_ns + outer[0].duration_ns
+    to_ns = trace_clock(profile, anchor)
+    span, = tr.spans()
+    assert a0 < to_ns(span.t0) < to_ns(span.t1) < a1
+    # 2 ms of sleep on each side: the mapping is off by far less
+    assert to_ns(span.t0) - a0 == pytest.approx(2e6, abs=1e6)

@@ -193,3 +193,31 @@ def test_engine_search_compiles(spec, deepfm, fused):
     assert _kernels(eng.search, *args) == {
         f"deepfm_score{suffix}", f"deepfm_grad{suffix}",
         f"neighbor_rank{suffix}"}
+
+
+def test_search_stage_map_puts_the_neighbour_gather_in_rank(spec, deepfm):
+    """The batch cells' search (Q=256, B=48, the default Pallas stages),
+    compiled for the chip: its (Q·B, D) gather of neighbour rows, the
+    longest op of a chip trace, is the rank stage's, as the
+    ``repro_rank`` scope says; the loop holds only loop stages."""
+    from repro.obs.profile import stage_map
+    cfg = SearchConfig(k=10, ef=64, budget=8)
+    opts = EngineOptions(rank_impl="pallas", measure_impl="pallas",
+                         grad_impl="pallas", interpret=False)
+    eng = build_engine(deepfm, cfg, opts)
+    q = 256
+    text = eng.compiled_text(
+        _like(deepfm.params, spec), spec((N, D)), spec((N, B), jnp.int32),
+        spec((q, D)), spec((q,), jnp.int32), spec((q,), jnp.int32),
+        spec((q,), jnp.float32))
+    stages = stage_map(text)
+    gathers = re.findall(rf"%([\w.\-]+) = f32\[{q * B},{D}\]\S* fusion\(",
+                         text)
+    assert gathers
+    for name in gathers:
+        assert stages[("jit_run", name)].stage == "rank"
+    counts = {}
+    for s in stages.values():
+        counts[s.stage] = counts.get(s.stage, 0) + 1
+    assert {"pop", "grad", "rank", "measure", "insert", "loop",
+            "init"} <= set(counts)
