@@ -409,52 +409,54 @@ def select_all_rank_fused_stage(x, grad, store, idx, valid):
 
 def default_insert_stage(state: EngineState, ids: jax.Array,
                          scores: jax.Array, mask: jax.Array) -> EngineState:
-    """Sorted-pool merge WITHOUT a general sort. The pool is desc-sorted and
-    only C ≪ ef candidates arrive per step, so (1) candidates are ordered by
-    a comparison-counted rank realized as a one-hot permutation (XLA's
-    generic sort and scatter are both far slower on CPU than these dense
-    ops), and (2) each output slot gathers from pool or sorted candidates by
-    merge-path counting — O(ef·C) vectorized comparisons total. Tie-breaking
-    is pool-first then candidate index order, i.e. bit-exact with a stable
-    desc sort of [pool | candidates]."""
+    """Sorted-pool merge with no sort and no gather. The pool is desc-sorted
+    and only C ≪ ef candidates arrive per step, so each candidate's merged
+    position is counted — its stable desc rank among the candidates plus
+    the pool entries >= it — and every output slot then reads by selects
+    on compares against static indices: O(ef·C) dense vector work.
+
+    No data-dependent read remains: XLA turns a gather of a few elements a
+    row into a per-element gather on TPU, ~10 ns an element, which made
+    this merge most of a search step there; XLA:CPU's generic sort and
+    scatter are likewise far slower than these dense ops.
+    Tie-breaking is pool-first then candidate index order, i.e. bit-exact
+    with a stable desc sort of [pool | candidates] truncated to ef."""
     Q, ef = state.pool_scores.shape
     C = scores.shape[1]
     ns = jnp.where(mask, scores, -jnp.inf)               # (Q, C)
     ni = jnp.where(mask, ids, -1)
     ne = ~mask
     p = state.pool_scores                                # (Q, ef) desc
-    # stable desc rank within candidates (unique) -> permutation via one-hot
+    # stable desc rank within candidates (a permutation of 0..C-1)
     gt = ns[:, :, None] < ns[:, None, :]                 # cand[k] > cand[j]
     eq_earlier = (ns[:, :, None] == ns[:, None, :]) \
         & (jnp.arange(C)[None, :] < jnp.arange(C)[:, None])[None]
     rank = jnp.sum(gt | eq_earlier, axis=2)              # (Q, C)
-    onehot = (rank[:, :, None]
-              == jnp.arange(C)[None, None, :]).astype(jnp.float32)
-    perm = jnp.einsum("qjc,j->qc", onehot,
-                      jnp.arange(C, dtype=jnp.float32)).astype(jnp.int32)
-    ns = jnp.take_along_axis(ns, perm, axis=1)           # (Q, C) desc
-    ni = jnp.take_along_axis(ni, perm, axis=1)
-    ne = jnp.take_along_axis(ne, perm, axis=1)
-    # merged position of sorted cand j: j + #(pool >= cand_j)
-    pos_c = jnp.arange(C)[None, :] + jnp.sum(
-        p[:, None, :] >= ns[:, :, None], axis=2)         # (Q, C)
-    # slot-major gather: n_c(t) candidates land before output slot t, so
-    # slot t holds cand[n_c] if its position is exactly t, else pool[t - n_c]
-    t = jnp.arange(ef)[None, :]
-    n_c = jnp.sum(pos_c[:, None, :] < t[:, :, None], axis=2)   # (Q, ef)
-    ip = t - n_c
-    jc = jnp.clip(n_c, 0, C - 1)
-    from_c = jnp.take_along_axis(pos_c, jc, axis=1) == t
+    # merged position of cand j: rank_j + #(pool >= cand_j); distinct
+    pos = rank + jnp.sum(p[:, None, :] >= ns[:, :, None], axis=2)
+    # slot t holds cand j iff pos_j == t (at most one j); otherwise the
+    # n_c(t) candidates placed before it shift it to pool[t - n_c(t)]
+    t = jnp.arange(ef)[None, None, :]
+    hit = pos[:, :, None] == t                           # (Q, C, ef)
+    from_c = jnp.any(hit, axis=1)                        # (Q, ef)
+    n_c = jnp.sum(pos[:, :, None] < t, axis=1)           # (Q, ef) in [0, C]
 
-    def pick(pool_v, cand_v):
-        a = jnp.take_along_axis(pool_v, jnp.clip(ip, 0, ef - 1), axis=1)
-        b = jnp.take_along_axis(cand_v, jc, axis=1)
+    def pick(pool_v, cand_v, fill):
+        # n_c(t) <= t, so a shift's fill is never selected
+        a = pool_v
+        for s in range(1, min(C, ef - 1) + 1):
+            shifted = jnp.concatenate(
+                [jnp.full((Q, s), fill, pool_v.dtype), pool_v[:, :ef - s]],
+                axis=1)
+            a = jnp.where(n_c == s, shifted, a)
+        # a max over the one hit, never a sum: a sum would turn -0.0 to 0.0
+        b = jnp.max(jnp.where(hit, cand_v[:, :, None], fill), axis=1)
         return jnp.where(from_c, b, a)
 
     return state._replace(
-        pool_scores=pick(p, ns),
-        pool_ids=pick(state.pool_ids, ni),
-        pool_expanded=pick(state.pool_expanded, ne))
+        pool_scores=pick(p, ns, -jnp.inf),
+        pool_ids=pick(state.pool_ids, ni, jnp.iinfo(jnp.int32).min),
+        pool_expanded=pick(state.pool_expanded, ne, False))
 
 
 # ---------------------------------------------------------------------------

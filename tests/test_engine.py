@@ -13,6 +13,7 @@ from repro.core import (EngineOptions, SearchConfig, brute_force_topk,
                         faithful_search_batch, inner_product_measure,
                         l2_measure, mlp_measure, recall, search_legacy,
                         search_measure)
+from repro.core.engine import EngineState, default_insert_stage
 from repro.graph import build_l2_graph
 from repro.models import deepfm as deepfm_lib
 
@@ -260,3 +261,57 @@ def test_engine_budget_and_counters():
     res2 = search_measure(m2, base_j, nbrs_j, queries_j, entries,
                           SearchConfig(k=5, ef=24, mode="guitar", budget=4))
     assert np.isfinite(np.asarray(res2.scores)).all()
+
+
+def _merge_case(rng, Q, ef, C):
+    """A desc pool with -inf tails and a candidate block, on a coarse score
+    grid (ties are common, -0.0 beside 0.0) with masked candidates."""
+    levels = rng.integers(2, 10)
+
+    def grid(shape):
+        return (rng.integers(-levels, levels, shape) / 4).astype(np.float32)
+
+    pool = grid((Q, ef))
+    pool[rng.random((Q, ef)) < 0.1] = -0.0
+    pool = -np.sort(-pool, axis=1, kind="stable")
+    for q, n in enumerate(rng.integers(0, ef + 1, Q)):
+        pool[q, n:] = -np.inf
+    scores = grid((Q, C))
+    scores[rng.random((Q, C)) < 0.1] = -0.0
+    mask = rng.random((Q, C)) < 0.7
+    state = EngineState(
+        jnp.asarray(pool),
+        jnp.asarray(rng.integers(0, 10_000, (Q, ef)), jnp.int32),
+        jnp.asarray(rng.random((Q, ef)) < 0.5), jnp.zeros((Q, 1), jnp.uint32),
+        *[jnp.zeros((Q,), jnp.int32)] * 3, jnp.zeros((Q,), jnp.bool_),
+        jnp.zeros((Q,), jnp.int32), jnp.zeros((Q,), jnp.float32))
+    ids = rng.integers(0, 10_000, (Q, C)).astype(np.int32)
+    return state, ids, scores, mask
+
+
+@pytest.mark.parametrize("ef", [16, 64])
+@pytest.mark.parametrize("C", [1, 8, 48])
+def test_insert_matches_stable_sort_oracle(C, ef):
+    """The gather-free pool merge equals, bit for bit in all three arrays,
+    a stable descending sort of [pool | candidates] cut to ef: pool first
+    on ties, then candidate order; masked candidates score -inf, id -1,
+    and count as expanded."""
+    rng = np.random.default_rng(1000 * C + ef)
+    merge = jax.jit(default_insert_stage)
+    for _ in range(8):
+        state, ids, scores, mask = _merge_case(rng, 16, ef, C)
+        out = merge(state, jnp.asarray(ids), jnp.asarray(scores),
+                    jnp.asarray(mask))
+        all_s = np.concatenate(
+            [np.asarray(state.pool_scores),
+             np.where(mask, scores, np.float32(-np.inf))], axis=1)
+        all_i = np.concatenate(
+            [np.asarray(state.pool_ids), np.where(mask, ids, -1)], axis=1)
+        all_e = np.concatenate([np.asarray(state.pool_expanded), ~mask],
+                               axis=1)
+        order = np.argsort(-all_s, axis=1, kind="stable")[:, :ef]
+        for got, arr in ((out.pool_scores, all_s), (out.pool_ids, all_i),
+                         (out.pool_expanded, all_e)):
+            want = np.take_along_axis(arr, order, axis=1)
+            assert np.asarray(got).dtype == want.dtype
+            assert np.asarray(got).tobytes() == want.tobytes()

@@ -14,6 +14,7 @@ this file.
 """
 from __future__ import annotations
 
+import math
 import os
 import re
 
@@ -221,3 +222,38 @@ def test_search_stage_map_puts_the_neighbour_gather_in_rank(spec, deepfm):
         counts[s.stage] = counts.get(s.stage, 0) + 1
     assert {"pop", "grad", "rank", "measure", "insert", "loop",
             "init"} <= set(counts)
+
+
+TWITCH_N = 739_991   # corpus rows of the batch benchmark cells
+_INDEXED = ("gather", "scatter", "dynamic-slice", "dynamic-update-slice")
+
+
+@pytest.mark.parametrize("mode", ["guitar", "sl2g"])
+def test_insert_stage_has_no_gather(spec, deepfm, mode):
+    """The pool merge compiles to dense selects on the chip: no gather of
+    the batch cells' search (Q=256, B=48, the default Pallas stages; SL2G
+    merges C = B = 48 candidates a step) belongs to the insert stage, and
+    its one indexed op is the visited bitmap's scatter. A per-element TPU
+    gather of a few elements a pool row made the merge most of a step."""
+    from repro.obs.profile import stage_map
+    cfg = SearchConfig(k=10, ef=64, budget=8, mode=mode)
+    opts = EngineOptions(rank_impl="pallas", measure_impl="pallas",
+                         grad_impl="pallas", interpret=False)
+    eng = build_engine(deepfm, cfg, opts)
+    q = 256
+    text = eng.compiled_text(
+        _like(deepfm.params, spec), spec((TWITCH_N, D)),
+        spec((TWITCH_N, B), jnp.int32), spec((q, D)), spec((q,), jnp.int32),
+        spec((q,), jnp.int32), spec((q,), jnp.float32))
+    stage = {name: s.stage for (_, name), s in stage_map(text).items()}
+    ops = re.findall(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = (\S+) ([\w\-]+)\(",
+                     text, re.M)
+    indexed = [(name, shape, op) for name, shape, op in ops
+               if op in _INDEXED and stage[name] == "insert"]
+    assert [op for _, _, op in indexed if op == "gather"] == []
+    # the (Q, ceil(N/32)) bitmap, which the compiler may flatten
+    words = q * -(-TWITCH_N // 32)
+    assert indexed and all(
+        op == "scatter" and shape.startswith("u32[")
+        and math.prod(map(int, shape[4:shape.index("]")].split(","))) == words
+        for _, shape, op in indexed), indexed
