@@ -21,6 +21,25 @@ the users a run queries, the order of its arrivals, and the measure's
 weights: the configuration's fixed weights with their hidden units
 permuted, which leaves the function, and so the work of a search, the
 same on every seed.
+
+Three pieces are the configuration's own, each with a default:
+
+- ``permute(params, key, m) -> params`` in the family's reference: a
+  permutation of its hidden units that keeps the function. Without it
+  the weights are a ReLU chain ``{"w": [...], "b": [...]}`` whose hidden
+  layers' outputs are permuted (``references/mlp_common.py:
+  permute_chain``), and weights with other keys are refused.
+- ``"queries": {"kind": "history", "length": L}`` in the configuration
+  file: each user's query row is the item rows of its last L clicks
+  under the corpus's click model, oldest first
+  (``data.history_queries``). Without it the query rows are the index's
+  users. Either way the index, and its key, are the corpus's and the
+  graph's alone.
+- ``"check": {"sample", "q_block"}`` in the configuration file: how many
+  answers the exhaustive top-k behind ``recall_at_10`` covers, and how
+  many queries it scores at a time (``CHECK`` holds the defaults). The
+  score check keeps its ``SAMPLE`` answers. The result line gives the
+  sizes used.
 """
 from __future__ import annotations
 
@@ -45,10 +64,16 @@ BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 # seconds of a run's window that a --trace 1 run records
 TRACE_SECONDS = 2.0
-# most answers the reference checks per run (a seeded sample beyond it)
+# most answers the reference rescores per run (a seeded sample beyond it)
 SAMPLE = 16384
 # most answers the reference search repeats per run
 SEARCH_SAMPLE = 1024
+# items a step of the exhaustive top-k scores
+BLOCK = 4096
+# sizes of the check where the configuration gives none: most answers
+# whose exhaustive top-k recall is read from, and the queries a step of
+# that top-k scores
+CHECK = {"sample": SAMPLE, "q_block": 128}
 GENERATOR = "bench/data.py:cluster_corpus v1"
 
 
@@ -221,25 +246,47 @@ def ensure_index(cell: Cell) -> str:
 
 def make_weights(cell: Cell, seed: int):
     """The measure's weights, on the device in one jitted call: the
-    configuration's weights (``weight_seed``) with the units of every
-    hidden layer permuted by ``seed``. A permutation of hidden units
-    leaves the function unchanged, so every seed does the same work."""
+    configuration's weights (``weight_seed``) with their hidden units
+    permuted by ``seed``, by the family's ``permute`` or, where it has
+    none, the ReLU chain's. A permutation of hidden units leaves the
+    function unchanged, so every seed does the same work."""
     import jax
-    import jax.numpy as jnp
+    from references.mlp_common import permute_chain
     m = cell.config["measure"]
+    permute = getattr(cell.ref, "permute", permute_chain)
 
     @jax.jit
     def build(base_key, perm_key):
-        p = cell.ref.init(base_key, m)
-        ws, bs = list(p["w"]), list(p["b"])
-        keys = jax.random.split(perm_key, len(ws) - 1)
-        for i in range(len(ws) - 1):
-            perm = jax.random.permutation(keys[i], ws[i].shape[1])
-            ws[i], bs[i] = ws[i][:, perm], bs[i][perm]
-            ws[i + 1] = ws[i + 1][perm, :]
-        return {"w": ws, "b": bs}
+        return permute(cell.ref.init(base_key, m), perm_key, m)
 
     return build(jax.random.PRNGKey(m["weight_seed"]), seed_key(seed))
+
+
+def query_rows(config: dict, items: np.ndarray,
+               index_path: str) -> np.ndarray:
+    """The configuration's query row of every user: the index's users,
+    or with ``"queries": {"kind": "history", "length": L}`` the item rows
+    of each user's last L clicks."""
+    q = config.get("queries")
+    if q is None:
+        return np.load(os.path.join(index_path, "users.npy"))
+    if q.get("kind") != "history" or set(q) != {"kind", "length"}:
+        raise ValueError(f"unknown queries {q!r}; known: "
+                         f"{{'kind': 'history', 'length': L}}")
+    c = config["corpus"]
+    return data.history_queries(items, c["users"], c["clusters"],
+                                c["data_seed"], int(q["length"]))
+
+
+def check_sizes(config: dict) -> dict:
+    """The sizes of the configuration's check: its ``check`` entry over
+    the defaults of ``CHECK``."""
+    given = config.get("check", {})
+    unknown = sorted(set(given) - set(CHECK))
+    if unknown:
+        raise ValueError(f"unknown check sizes {unknown}; known: "
+                         f"{sorted(CHECK)}")
+    return {k: int(given.get(k, v)) for k, v in CHECK.items()}
 
 
 class Env:
@@ -309,7 +356,7 @@ class Env:
         path = ensure_index(self.cell)
         index = load_index(path)
         self.index_path = path
-        self.users = np.load(os.path.join(path, "users.npy"))
+        self.users = query_rows(self.cell.config, index.base, path)
         self.query_dim = int(self.users.shape[1])
         self.n_items = int(index.base.shape[0])
         self.degree = int(index.neighbors.shape[1])
@@ -386,15 +433,15 @@ class Reference:
     once per process: exhaustive top-k, per-pair scores at a stated
     precision, and the graph search of ``references/search.py``."""
 
-    def __init__(self, cell: Cell, index_path: str, block: int = 4096,
-                 q_block: int = 128):
+    def __init__(self, cell: Cell, index_path: str):
         import jax
         import jax.numpy as jnp
         items = np.load(os.path.join(index_path, "items.npy"))
         self.cell = cell
         self.m = cell.config["measure"]
         self.n = items.shape[0]
-        self.block, self.q_block = block, q_block
+        block = self.block = BLOCK
+        self.q_block = check_sizes(cell.config)["q_block"]
         arrays = np.load(os.path.join(index_path, "arrays.npz"))
         self.neighbors = jnp.asarray(arrays["neighbors"])
         self.entry = int(load_json(os.path.join(index_path,
@@ -496,8 +543,12 @@ def compare(env: Env, out: dict, ref: Reference) -> dict:
     search_miss  mean share of the reference search's top-k ids missing
                  from the answer, over a smaller seeded sample
     bad_rows     completed answers malformed on their face (all of them)
-    missing      requests due in the window that never completed"""
+    missing      requests due in the window that never completed
+
+    recall@k is read over the configuration's ``check.sample`` answers,
+    the same rows as score_gap's where that is ``SAMPLE``."""
     lim = env.cell.config["limits"]
+    sizes = check_sizes(env.cell.config)
     done = out["completed"]
     idx = sample_rows(done, env.seed)
     ids, scores = done["ids"][idx], done["scores"][idx]
@@ -508,9 +559,10 @@ def compare(env: Env, out: dict, ref: Reference) -> dict:
         gap = float(np.max(np.abs(scores - exact)[valid]))
     else:
         gap = math.inf
-    true = ref.topk(env.weights, qs, env.k)
+    top = sample_rows(done, env.seed, sizes["sample"])
+    true = ref.topk(env.weights, env.users[done["user"][top]], env.k)
     hits = [len(set(a.tolist()) & set(b.tolist()))
-            for a, b in zip(ids, true)]
+            for a, b in zip(done["ids"][top], true)]
     recall = float(np.mean(hits) / env.k) if hits else 0.0
     few = sample_rows(done, env.seed, SEARCH_SAMPLE)
     ref_ids = ref.search(env.weights, env.users[done["user"][few]],
@@ -525,7 +577,8 @@ def compare(env: Env, out: dict, ref: Reference) -> dict:
         "missing": {"value": int(out["missing"]), "limit": lim["missing"]},
     }
     return {"checks": checks, "recall": recall, "sample": len(idx),
-            "ids": ids, "queries": qs, "valid": valid}
+            "recall_sample": len(top), "sizes": sizes, "ids": ids,
+            "queries": qs, "valid": valid}
 
 
 # ---------------------------------------------------------------------------
@@ -608,7 +661,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     t0 = time.perf_counter()
     ref = Reference(cell, env.index_path)
     cmp = compare(env, out, ref)
-    log(f"reference: {cmp['sample']} answers checked in "
+    log(f"reference: {cmp['sample']} answers rescored, "
+        f"{cmp['recall_sample']} searched exhaustively in "
         f"{time.perf_counter() - t0:.3f} s, recall@{env.k} "
         f"{cmp['recall']:.4f}")
     checks = cmp["checks"]
@@ -634,7 +688,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     if red is not None:
         result["breakdown"] = {"device_ops": red["device_ops"],
                                "idle_gaps": red["idle_gaps"]}
+    result["check_sizes"] = cmp["sizes"]
     result["checks"] = checks
-    lines = [f"check {k}: {c['value']} limit {c['limit']}"
-             for k, c in checks.items()]
+    lines = ["check sizes: " + ", ".join(
+        f"{k} {v}" for k, v in cmp["sizes"].items())]
+    lines += [f"check {k}: {c['value']} limit {c['limit']}"
+              for k, c in checks.items()]
     return result, lines

@@ -1,6 +1,7 @@
 """Pieces shared by the plain references: the matmul at a stated
-precision, ReLU MLP layers, and the forward FLOPs of a layer stack.
-Imports nothing of the program under test."""
+precision, ReLU MLP layers, the permutation of a ReLU chain's hidden
+units, and the forward FLOPs of a layer stack. Imports nothing of the
+program under test."""
 from __future__ import annotations
 
 import math
@@ -44,6 +45,26 @@ def apply_layers(params: dict, h, precision: str, first: int = 0):
         if i < n - 1:
             h = jax.nn.relu(h)
     return h
+
+
+def permute_chain(params: dict, key, m: dict) -> dict:
+    """The weights of a ReLU chain ``{"w": [...], "b": [...]}`` with the
+    units of every hidden layer permuted by ``key``: the same function.
+    The permutation of a family whose reference defines no ``permute``;
+    weights with any other key are refused, since their units are not a
+    chain's."""
+    if set(params) != {"w", "b"}:
+        raise ValueError(
+            f"weights with keys {sorted(params)} are no ReLU chain "
+            f"{{'w', 'b'}}: the family's reference must define "
+            f"permute(params, key, m)")
+    ws, bs = list(params["w"]), list(params["b"])
+    keys = jax.random.split(key, len(ws) - 1)
+    for i in range(len(ws) - 1):
+        perm = jax.random.permutation(keys[i], ws[i].shape[1])
+        ws[i], bs[i] = ws[i][:, perm], bs[i][perm]
+        ws[i + 1] = ws[i + 1][perm, :]
+    return {"w": ws, "b": bs}
 
 
 def layer_flops(dims) -> int:

@@ -8,8 +8,10 @@ the real cells at a CPU size with the chip check skipped:
 - each fault the cells can have, planted under the timed path, makes the
   run come out not correct: a step that returns its state unchanged,
   half of the batch left out, an answer altered where it is produced,
-  the rank stage handed the reversed gradient, the gradient zeroed.
-  (The exchange between chips is no fault of these one-chip cells.)
+  the rank stage handed the reversed gradient (over the search_miss
+  limit), the gradient zeroed (seen by search_miss; on the chip it reads
+  over the limit, at this size under it). (The exchange between chips is
+  no fault of these one-chip cells.)
 """
 import contextlib
 
@@ -38,6 +40,7 @@ def test_sound_run_is_correct(tiny_root, cell):
     assert res["correct"], lines
     assert res["attempted"] > 0 and res["failed"] == 0
     assert res["checks"]["search_miss"]["value"] == 0, lines
+    assert res["check_sizes"] == harness.CHECK
     assert list(res)[-1] == "checks"
 
 
@@ -140,3 +143,14 @@ def test_stage_fault_is_seen_by_search_miss(tiny_root, cell, fault,
     with planted(fault, monkeypatch):
         res, lines = run(tiny_root, cell)
     assert res["checks"]["search_miss"]["value"] >= 0.1, lines
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_reversed_rank_is_not_correct(tiny_root, cell, monkeypatch):
+    """The rank stage handed the reversed gradient returns none of the
+    reference search's ids: search_miss passes its limit."""
+    with planted("rank_reversed", monkeypatch):
+        res, lines = run(tiny_root, cell)
+    check = res["checks"]["search_miss"]
+    assert check["value"] > check["limit"], lines
+    assert not res["correct"], lines
