@@ -4,6 +4,11 @@ users, with one host fetch of the results per batch (the way ``serve
 --runtime oneshot`` runs them). The next batch is sent when the previous
 one has come back.
 
+With ``--trace 1``, once the window's last answer is in, the stage map
+of the compiled search at the batch's shapes (``repro.obs.profile.
+stage_map``, (module, instruction) -> engine stage) goes into
+``out["stages"]`` for the per-stage readers (``bench/stages.py``).
+
 Traffic parameters: ``batch``.
 """
 from __future__ import annotations
@@ -11,6 +16,8 @@ from __future__ import annotations
 import time
 
 import numpy as np
+
+from harness import log
 
 
 def run(env) -> dict:
@@ -47,6 +54,7 @@ def run(env) -> dict:
             res = batch(users)
         rows.append((users, res))
     t1 = time.perf_counter()
+    env.last_answer()
     if env.trace and traced is None:
         env.stop_trace()
         traced = {"batches": len(rows), "host_s": t1 - t_trace}
@@ -69,4 +77,20 @@ def run(env) -> dict:
             n_eval=int(sum(int(b["n_eval"].sum()) for b in tb)),
             n_grad=int(sum(int(b["n_grad"].sum()) for b in tb)))
         out["traced"] = traced
+        t_map = time.perf_counter()
+        out["stages"] = stage_map(env, B, entries)
+        log(f"stage map of the compiled search: {len(out['stages'])} "
+            f"instructions in {time.perf_counter() - t_map:.3f} s, after "
+            f"the window")
     return out
+
+
+def stage_map(env, B: int, entries) -> dict:
+    """(module, instruction) -> engine stage of the program a batch runs,
+    read back through JAX's compile caches (the warm-up compiled it)."""
+    import jax.numpy as jnp
+    from repro.obs.profile import stage_map as hlo_stage_map
+    q = jnp.asarray(env.users[:B])
+    text = env.engine.compiled_text(env.params, env.store, env.neighbors, q,
+                                    entries)
+    return {k: v.stage for k, v in hlo_stage_map(text).items()}

@@ -103,6 +103,7 @@ def run(env) -> dict:
             done[c.rid] = c
         if not (rt.queue or rt.in_flight):
             break
+    env.last_answer()
     ok = sorted(r for r, c in done.items() if c.status == "ok")
     lat = np.full(n, np.inf)
     for r in ok:

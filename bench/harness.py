@@ -22,7 +22,7 @@ weights: the configuration's fixed weights with their hidden units
 permuted, which leaves the function, and so the work of a search, the
 same on every seed.
 
-Three pieces are the configuration's own, each with a default:
+Two pieces are the configuration's own, each with a default:
 
 - ``permute(params, key, m) -> params`` in the family's reference: a
   permutation of its hidden units that keeps the function. Without it
@@ -35,11 +35,9 @@ Three pieces are the configuration's own, each with a default:
   (``data.history_queries``). Without it the query rows are the index's
   users. Either way the index, and its key, are the corpus's and the
   graph's alone.
-- ``"check": {"sample", "q_block"}`` in the configuration file: how many
-  answers the exhaustive top-k behind ``recall_at_10`` covers, and how
-  many queries it scores at a time (``CHECK`` holds the defaults). The
-  score check keeps its ``SAMPLE`` answers. The result line gives the
-  sizes used.
+
+Only a cell that reports ``recall_at_10`` runs the exhaustive top-k
+behind it.
 """
 from __future__ import annotations
 
@@ -70,10 +68,8 @@ SAMPLE = 16384
 SEARCH_SAMPLE = 1024
 # items a step of the exhaustive top-k scores
 BLOCK = 4096
-# sizes of the check where the configuration gives none: most answers
-# whose exhaustive top-k recall is read from, and the queries a step of
-# that top-k scores
-CHECK = {"sample": SAMPLE, "q_block": 128}
+# queries a step of the exhaustive top-k scores
+Q_BLOCK = 128
 GENERATOR = "bench/data.py:cluster_corpus v1"
 
 
@@ -278,17 +274,6 @@ def query_rows(config: dict, items: np.ndarray,
                                 c["data_seed"], int(q["length"]))
 
 
-def check_sizes(config: dict) -> dict:
-    """The sizes of the configuration's check: its ``check`` entry over
-    the defaults of ``CHECK``."""
-    given = config.get("check", {})
-    unknown = sorted(set(given) - set(CHECK))
-    if unknown:
-        raise ValueError(f"unknown check sizes {unknown}; known: "
-                         f"{sorted(CHECK)}")
-    return {k: int(given.get(k, v)) for k, v in CHECK.items()}
-
-
 class Env:
     """What a driver gets: the set-up system, the seeded streams, the
     window's clock and the profiler."""
@@ -299,6 +284,7 @@ class Env:
         self.trace, self.t_start = trace, t_start
         self.traffic = cell.traffic
         self.t_window: Optional[float] = None
+        self.window_compiles: Optional[int] = None
         self.trace_seconds = TRACE_SECONDS
         self.trace_dir = os.path.join(cell.bench, ".cache", "trace",
                                       cell.name)
@@ -321,6 +307,16 @@ class Env:
             f"after process start (engine ready at "
             f"{self.t_ready - self.t_start:.3f} s, then warm-up)")
         return self.t_window
+
+    def last_answer(self) -> None:
+        """The window's last answer is in: logs the programs compiled
+        since the window opened, which should be none. What the driver
+        does after this, such as reading back the compiled search, is
+        not the window's. Every driver calls it: ``run_cell`` refuses
+        one that does not, so this line is in every run's log."""
+        n = self.window_compiles = COMPILES[0] - self.compiles_at_open
+        log(f"programs compiled or read from the cache from the window's "
+            f"start to its last answer: {n}")
 
     def start_trace(self) -> None:
         import jax
@@ -441,7 +437,6 @@ class Reference:
         self.m = cell.config["measure"]
         self.n = items.shape[0]
         block = self.block = BLOCK
-        self.q_block = check_sizes(cell.config)["q_block"]
         arrays = np.load(os.path.join(index_path, "arrays.npz"))
         self.neighbors = jnp.asarray(arrays["neighbors"])
         self.entry = int(load_json(os.path.join(index_path,
@@ -485,9 +480,9 @@ class Reference:
              precision: str = "float32") -> np.ndarray:
         import jax.numpy as jnp
         out = []
-        for s in range(0, len(queries), self.q_block):
-            qb = queries[s:s + self.q_block]
-            pad = self.q_block - len(qb)
+        for s in range(0, len(queries), Q_BLOCK):
+            qb = queries[s:s + Q_BLOCK]
+            pad = Q_BLOCK - len(qb)
             qj = jnp.asarray(np.pad(qb, ((0, pad), (0, 0))))
             _, i = self._topk(params, self.blocks, qj, k=k,
                               precision=precision)
@@ -535,6 +530,12 @@ def search_miss(ids: np.ndarray, ref_ids: np.ndarray) -> float:
     return float(np.mean(miss)) if miss else math.inf
 
 
+def reports_recall(cell: Cell, k: int) -> bool:
+    """Whether ``recall_at_<k>`` is among the cell's end-to-end metrics:
+    only then does the check run the exhaustive top-k."""
+    return any(m["name"] == f"recall_at_{k}" for m in cell.end_to_end)
+
+
 def compare(env: Env, out: dict, ref: Reference) -> dict:
     """The numbers compared, each with its limit, and recall@k.
 
@@ -545,10 +546,9 @@ def compare(env: Env, out: dict, ref: Reference) -> dict:
     bad_rows     completed answers malformed on their face (all of them)
     missing      requests due in the window that never completed
 
-    recall@k is read over the configuration's ``check.sample`` answers,
-    the same rows as score_gap's where that is ``SAMPLE``."""
+    recall@k is read over score_gap's sampled answers, and only in a cell
+    that reports it (else recall is None and no exhaustive top-k runs)."""
     lim = env.cell.config["limits"]
-    sizes = check_sizes(env.cell.config)
     done = out["completed"]
     idx = sample_rows(done, env.seed)
     ids, scores = done["ids"][idx], done["scores"][idx]
@@ -559,11 +559,12 @@ def compare(env: Env, out: dict, ref: Reference) -> dict:
         gap = float(np.max(np.abs(scores - exact)[valid]))
     else:
         gap = math.inf
-    top = sample_rows(done, env.seed, sizes["sample"])
-    true = ref.topk(env.weights, env.users[done["user"][top]], env.k)
-    hits = [len(set(a.tolist()) & set(b.tolist()))
-            for a, b in zip(done["ids"][top], true)]
-    recall = float(np.mean(hits) / env.k) if hits else 0.0
+    recall = None
+    if reports_recall(env.cell, env.k):
+        true = ref.topk(env.weights, qs, env.k)
+        hits = [len(set(a.tolist()) & set(b.tolist()))
+                for a, b in zip(ids, true)]
+        recall = float(np.mean(hits) / env.k) if hits else 0.0
     few = sample_rows(done, env.seed, SEARCH_SAMPLE)
     ref_ids = ref.search(env.weights, env.users[done["user"][few]],
                          env.cell.config["search"])
@@ -576,8 +577,7 @@ def compare(env: Env, out: dict, ref: Reference) -> dict:
                      "limit": lim["bad_rows"]},
         "missing": {"value": int(out["missing"]), "limit": lim["missing"]},
     }
-    return {"checks": checks, "recall": recall, "sample": len(idx),
-            "recall_sample": len(top), "sizes": sizes, "ids": ids,
+    return {"checks": checks, "recall": recall, "sample": len(idx), "ids": ids,
             "queries": qs, "valid": valid}
 
 
@@ -644,8 +644,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     env = Env(cell, seed, seconds, trace, t_start)
     env.setup()
     out = cell.driver.run(env)
-    log(f"programs compiled or read from the cache from the window's "
-        f"start to its last answer: {COMPILES[0] - env.compiles_at_open}")
+    if env.window_compiles is None:
+        raise RuntimeError(f"driver {cell.traffic['driver']!r} returned "
+                           f"without calling env.last_answer()")
     setup_s = env.t_window - t_start
     device["memory_peak_bytes"] = memory_peak(devs)
     red = None
@@ -654,6 +655,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         red = trace_reduce.reduce_trace(
             trace_reduce.find_xplane(env.trace_dir), devices=cell.chips)
         shutil.rmtree(env.trace_dir, ignore_errors=True)
+        if red["overlap_s"] > 0:
+            log(f"trace: ops inside a loop overlap by {red['overlap_s']} s; "
+                f"the loop's own time reads that much low")
         device["busy_s"] = red["busy_s"]
         device["window_s"] = red["window_s"]
     degree = env.degree
@@ -661,16 +665,20 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     t0 = time.perf_counter()
     ref = Reference(cell, env.index_path)
     cmp = compare(env, out, ref)
-    log(f"reference: {cmp['sample']} answers rescored, "
-        f"{cmp['recall_sample']} searched exhaustively in "
-        f"{time.perf_counter() - t0:.3f} s, recall@{env.k} "
-        f"{cmp['recall']:.4f}")
+    if cmp["recall"] is None:
+        scan = f"no exhaustive top-k (recall@{env.k} not reported)"
+    else:
+        scan = (f"the same searched exhaustively, "
+                f"recall@{env.k} {cmp['recall']:.4f}")
+    log(f"reference: {cmp['sample']} answers rescored, {scan}, in "
+        f"{time.perf_counter() - t0:.3f} s")
     checks = cmp["checks"]
     # a number whose limit is null is read and printed, not compared
     correct = all(c["value"] <= c["limit"] for c in checks.values()
                   if c["limit"] is not None)
-    values = dict(out["e2e"], setup_s=setup_s,
-                  **{f"recall_at_{env.k}": cmp["recall"]})
+    values = dict(out["e2e"], setup_s=setup_s)
+    if cmp["recall"] is not None:
+        values[f"recall_at_{env.k}"] = cmp["recall"]
     metrics = {}
     if not trace:
         for m in cell.end_to_end:
@@ -688,10 +696,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     if red is not None:
         result["breakdown"] = {"device_ops": red["device_ops"],
                                "idle_gaps": red["idle_gaps"]}
-    result["check_sizes"] = cmp["sizes"]
     result["checks"] = checks
-    lines = ["check sizes: " + ", ".join(
-        f"{k} {v}" for k, v in cmp["sizes"].items())]
-    lines += [f"check {k}: {c['value']} limit {c['limit']}"
-              for k, c in checks.items()]
+    lines = [f"check {k}: {c['value']} limit {c['limit']}"
+             for k, c in checks.items()]
     return result, lines

@@ -1,6 +1,6 @@
-"""What a configuration owns: its family's permutation of the weights, its
-query rows and the sizes of its check, each with a default that leaves
-the declared cells as they were.
+"""What a configuration owns: its family's permutation of the weights and
+its query rows, each with a default that leaves the declared cells as
+they were.
 
 - ``make_weights`` of both declared cells is bit-identical to the chain
   permutation the harness had before a family could own one (kept here as
@@ -8,15 +8,14 @@ the declared cells as they were.
 - weights with keys other than a chain's, from a family with no
   ``permute``, are refused;
 - without ``queries`` the query rows are the index's users, and the index
-  key is the corpus's and the graph's whatever ``queries`` and ``check``
-  say;
+  key is the corpus's and the graph's whatever ``queries`` says;
 - a history row holds item rows of its user's clicks, drawn as the
   program's ``make_interactions`` draws them;
-- a smaller ``check.sample`` shrinks the exhaustive top-k and leaves the
-  score check's sample as it was;
+- the check rescores ``SAMPLE`` answers, runs the exhaustive top-k over
+  the same answers only where the cell reports recall, and repeats the
+  search over ``SEARCH_SAMPLE``;
 - a test-only family with a non-chain parameter, its own ``permute`` and
-  history queries runs end to end at a CPU size and is correct, with its
-  own check sizes in the result line.
+  history queries runs end to end at a CPU size and is correct.
 """
 import json
 import os
@@ -32,13 +31,12 @@ from conftest import ROOT
 
 CELLS = ["deepfm-twitch.batch", "mlp-twitch.batch"]
 SEEDS = [0, 7, 2**33 + 5]
-# the index key both declared configurations had before queries and
-# check sizes were a configuration's own (their corpus and graph)
+# the index key both declared configurations had before queries were a
+# configuration's own (their corpus and graph)
 TWITCH_KEY = "371548b522d1f2b4"
 
 HIST = "histgate-tiny"
 HIST_CELL = HIST + ".batch"
-HIST_CHECK = {"sample": 256, "q_block": 32}
 
 # A measure whose weights are no ReLU chain: a gate on each hidden unit,
 # sigmoid(relu([x, q] W0 + b0) * gate W1 + b1), over a history query.
@@ -151,7 +149,7 @@ def hist_root(tiny_root):
                measure={"family": "histgate", "item_dim": 40,
                         "query_dim": 3 * 40, "hidden": 32, "weight_seed": 0},
                queries={"kind": "history", "length": 3},
-               check=HIST_CHECK, kernels={"rank": "neighbor_rank"})
+               kernels={"rank": "neighbor_rank"})
     files = {"references/histgate.py": HIST_REF,
              "adapters/histgate.py": HIST_ADAPTER,
              f"configs/{HIST}.json": json.dumps(cfg)}
@@ -168,7 +166,7 @@ def hist_root(tiny_root):
                                   "traffic": "batch", "chips": 1,
                                   "why": "test-only history family"})
         for m in spec["end_to_end"]:
-            if m["name"] == "qps":
+            if m["name"] in ("qps", "recall_at_10"):
                 m["workloads"].append(HIST_CELL)
         with open(path, "w") as f:
             json.dump(spec, f)
@@ -217,8 +215,7 @@ def test_index_key_is_corpus_and_graph_only():
         with open(os.path.join(ROOT, "bench", "configs", name + ".json")) as f:
             cfg = json.load(f)
         assert harness.index_key(cfg) == TWITCH_KEY
-        hist = dict(cfg, queries={"kind": "history", "length": 20},
-                    check=HIST_CHECK)
+        hist = dict(cfg, queries={"kind": "history", "length": 20})
         assert harness.index_key(hist) == TWITCH_KEY
 
 
@@ -292,17 +289,11 @@ def test_cluster_ids_are_cluster_corpus_draws():
     assert dist.diagonal().max() < 0.5
 
 
-def test_check_sizes_default_and_refuse_unknown():
-    assert harness.check_sizes({}) == {"sample": 16384, "q_block": 128}
-    assert harness.check_sizes({"check": {"sample": 64}})["sample"] == 64
-    for bad in ("samples", "block", "search_sample"):
-        with pytest.raises(ValueError, match="unknown check sizes"):
-            harness.check_sizes({"check": {bad: 64}})
-
-
-def test_recall_sample_leaves_score_sample():
-    """check.sample sizes the exhaustive top-k alone: score_gap still
-    rescores SAMPLE answers and the reference search SEARCH_SAMPLE."""
+@pytest.mark.parametrize("recall", [True, False])
+def test_check_sample_sizes(recall):
+    """score_gap rescores SAMPLE answers, the exhaustive top-k covers the
+    same answers where the cell reports recall and runs nowhere else,
+    and the reference search repeats SEARCH_SAMPLE."""
     n, k = harness.SAMPLE + 500, 10
     rng = np.random.default_rng(0)
     ids = np.tile(np.arange(k, dtype=np.int32), (n, 1))
@@ -324,16 +315,18 @@ def test_recall_sample_leaves_score_sample():
             return np.tile(np.arange(k), (len(qs), 1))
 
     limits = {"score_gap": 0, "search_miss": 0, "bad_rows": 0, "missing": 0}
-    for sample in (harness.SAMPLE, 40):
-        env = types.SimpleNamespace(
-            cell=types.SimpleNamespace(config={
-                "limits": limits, "search": {}, "check": {"sample": sample}}),
-            seed=3, users=np.zeros((50, 4), np.float32), n_items=100,
-            weights=None, k=k)
-        cmp = harness.compare(env, {"completed": done, "missing": 0}, Ref())
-        assert seen == {"rescored": harness.SAMPLE, "top_k": sample,
-                        "searched": harness.SEARCH_SAMPLE}
-        assert cmp["recall"] == 1.0 and cmp["sizes"]["sample"] == sample
+    env = types.SimpleNamespace(
+        cell=types.SimpleNamespace(
+            config={"limits": limits, "search": {}},
+            end_to_end=[{"name": "recall_at_10"}] if recall else []),
+        seed=3, users=np.zeros((50, 4), np.float32), n_items=100,
+        weights=None, k=k)
+    cmp = harness.compare(env, {"completed": done, "missing": 0}, Ref())
+    want = {"rescored": harness.SAMPLE, "searched": harness.SEARCH_SAMPLE}
+    if recall:
+        want["top_k"] = harness.SAMPLE
+    assert seen == want
+    assert cmp["recall"] == (1.0 if recall else None)
 
 
 def test_history_family_runs_correct(hist_root):
@@ -343,6 +336,4 @@ def test_history_family_runs_correct(hist_root):
     assert res["correct"], text
     assert res["attempted"] > 0 and res["failed"] == 0
     assert res["checks"]["search_miss"]["value"] == 0, text
-    assert res["check_sizes"] == HIST_CHECK
-    assert lines[0] == "check sizes: sample 256, q_block 32"
-    assert list(res)[-2:] == ["check_sizes", "checks"]
+    assert list(res)[-1] == "checks"

@@ -40,7 +40,10 @@ def test_sound_run_is_correct(tiny_root, cell):
     assert res["correct"], lines
     assert res["attempted"] > 0 and res["failed"] == 0
     assert res["checks"]["search_miss"]["value"] == 0, lines
-    assert res["check_sizes"] == harness.CHECK
+    # the exhaustive top-k runs only where the cell reports recall_at_10
+    scan = harness.reports_recall(harness.find_cell(cell, tiny_root), 10)
+    assert scan == cell.endswith(".batch")
+    assert ("recall_at_10" in res["metrics"]) == scan
     assert list(res)[-1] == "checks"
 
 

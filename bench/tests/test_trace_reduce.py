@@ -91,3 +91,28 @@ def test_roofline_shares_stay_under_100(reduced):
     for k in ("neighbor_rank", "deepfm_score", "deepfm_grad"):
         share = work.roofline_share(ctx, k)
         assert 0 < share < 100
+
+
+@pytest.mark.parametrize("spans, own", [
+    # a loop (container) around two ops and a gap, then an op after it
+    ([(0, 10, True), (1, 4, False), (6, 9, False), (10, 12, False)],
+     [4, 3, 3, 2]),
+    # a loop in a loop: each gives up only what lies directly inside it
+    ([(0, 10, True), (1, 8, True), (2, 5, False), (8, 9, False)],
+     [2, 4, 3, 1]),
+    # an op that overlaps another op takes nothing from it
+    ([(0, 10, True), (1, 6, False), (2, 4, False)], [3, 5, 2]),
+    # children that overlap in a loop leave it a negative own time
+    ([(0, 4, True), (0, 3, False), (1, 4, False)], [-2, 3, 3]),
+])
+def test_own_times(spans, own):
+    assert tr._own_times(spans) == own
+
+
+def test_instruction_time_adds_up_to_the_busy_time(reduced):
+    assert reduced["overlap_s"] == 0
+    assert sum(reduced["instr_s"].values()) == pytest.approx(
+        reduced["busy_s"], rel=1e-9)
+    ops = sum(v for (_, name), v in reduced["instr_s"].items()
+              if tr.op_base(name) not in tr.CONTAINERS)
+    assert ops == pytest.approx(sum(reduced["op_s"].values()), rel=1e-9)

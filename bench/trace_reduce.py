@@ -7,6 +7,11 @@ metrics read:
   ``XLA Ops`` line, clipped to the window;
 - device time per op, keyed by the HLO instruction's name without its
   numeric suffix (a Pallas kernel's ``name``, e.g. ``neighbor_rank``);
+- device time per (module, instruction), the module read from the
+  device's ``XLA Modules`` line, which a stage map of the compiled
+  program (``obs.profile.stage_map``) prices by engine stage; a
+  container (a loop) is credited its own time, its span less the ops
+  inside it, so these times add up to the busy time;
 - the idle gaps between busy intervals, each labelled by the innermost
   host event on the window's thread that covers its middle.
 
@@ -23,12 +28,14 @@ import re
 WINDOW = "bench/window"
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 # ops that contain other ops of the same line
 CONTAINERS = ("while", "conditional", "call")
 # gaps shorter than this are summed under one label
 SMALL_GAP_NS = 10_000
 
 _NAME = re.compile(r"^%?([A-Za-z0-9_\-]+?)(?:\.\d+)*(?:\s|=|$)")
+_INSTR = re.compile(r"^%?([^\s=]+)")
 _LAYOUT = re.compile(r"\{[^{}]*\}")
 
 
@@ -45,6 +52,11 @@ def op_base(name: str) -> str:
     """'%neighbor_rank.6 = f32[...] custom-call(...)' -> 'neighbor_rank'."""
     m = _NAME.match(name.strip())
     return m.group(1) if m else name.split()[0]
+
+
+def op_instr(name: str) -> str:
+    """'%fusion.117 = f32[...] fusion(...)' -> 'fusion.117'."""
+    return _INSTR.match(name.strip()).group(1)
 
 
 def op_label(name: str) -> str:
@@ -73,6 +85,34 @@ def union(intervals):
 def _events(line):
     for ev in line.events:
         yield ev.name, float(ev.start_ns), float(ev.duration_ns)
+
+
+def _module_at(mods, starts, t):
+    """The module whose execution on the device covers time ``t``, or
+    ''; ``mods`` is a sorted list of (start, end, module)."""
+    i = bisect.bisect_right(starts, t) - 1
+    return mods[i][2] if i >= 0 and t < mods[i][1] else ""
+
+
+def _own_times(spans):
+    """Each (start, end, is_container) span's own time: its length less
+    the spans nested directly inside it, where only a container holds
+    others. A container whose children overlap one another gets a
+    negative own time, left as it is (``reduce_profile`` reports the
+    sum as ``overlap_s``)."""
+    order = sorted(range(len(spans)),
+                   key=lambda i: (spans[i][0], -spans[i][1], not spans[i][2]))
+    own = [e - s for s, e, _ in spans]
+    stack = []
+    for i in order:
+        s, e, cont = spans[i]
+        while stack and spans[stack[-1]][1] <= s:
+            stack.pop()
+        if stack and e <= spans[stack[-1]][1]:
+            own[stack[-1]] -= e - s
+        if cont:
+            stack.append(i)
+    return own
 
 
 def _label_gaps(gaps, host):
@@ -105,16 +145,25 @@ def reduce_profile(pd, devices: int = 1) -> dict:
 
     Returns {'window_s', 'busy_s' (mean over the devices), 'op_s' (op
     base name -> device seconds, summed over devices, containers left
-    out), 'device_ops' (top 10 [label, seconds]), 'idle_gaps' (top 10
-    [host label, seconds]), 'n_device_events'}."""
+    out), 'instr_s' ((module, instruction) -> device seconds, the mean
+    over the devices, a container's own time only), 'overlap_s' (the
+    mean over the devices of the time by which a container's children
+    overlap one another, 0 where they tile it), 'device_ops' (top 10
+    [label, seconds]), 'idle_gaps' (top 10 [host label, seconds]),
+    'n_device_events'}."""
     window = None
     host = []
     dev_lines = []
     for plane in pd.planes:
         if plane.name.startswith(DEVICE_PREFIX):
             idx = int(plane.name[len(DEVICE_PREFIX):])
-            if idx < devices:
-                dev_lines += [ln for ln in plane.lines if ln.name == OPS_LINE]
+            lines = {ln.name: ln for ln in plane.lines}
+            if idx < devices and OPS_LINE in lines:
+                mods = sorted(
+                    (s, s + d, n.split("(")[0])
+                    for n, s, d in _events(lines[MODULES_LINE])
+                ) if MODULES_LINE in lines else []
+                dev_lines.append((lines[OPS_LINE], mods))
             continue
         for line in plane.lines:
             evs = list(_events(line))
@@ -127,25 +176,33 @@ def reduce_profile(pd, devices: int = 1) -> dict:
         raise ValueError(f"no '{WINDOW}' annotation in the trace")
     w0, w1 = window
     op_s = collections.Counter()
+    instr_s = collections.Counter()
+    overlap = 0.0
     label_s = collections.Counter()
     busy_total = 0.0
     gaps_all = []
     n_events = 0
-    for line in dev_lines:
-        spans = []
+    for line, mods in dev_lines:
+        starts = [m[0] for m in mods]
+        spans, names = [], []
         for name, s, d in _events(line):
             e = s + d
             if e <= w0 or s >= w1:
                 continue
             n_events += 1
-            cs, ce = max(s, w0), min(e, w1)
-            spans.append((cs, ce))
-            base = op_base(name)
-            if base in CONTAINERS:
+            spans.append((max(s, w0), min(e, w1),
+                          op_base(name) in CONTAINERS))
+            names.append(name)
+        for (cs, ce, cont), own, name in zip(spans, _own_times(spans), names):
+            key = (_module_at(mods, starts, cs), op_instr(name))
+            if cont:
+                instr_s[key] += own * 1e-9
+                overlap -= min(own, 0.0) * 1e-9
                 continue
-            op_s[base] += (ce - cs) * 1e-9
+            op_s[op_base(name)] += (ce - cs) * 1e-9
+            instr_s[key] += (ce - cs) * 1e-9
             label_s[op_label(name)] += (ce - cs) * 1e-9
-        busy = union(spans)
+        busy = union([(cs, ce) for cs, ce, _ in spans])
         busy_total += sum(e - s for s, e in busy)
         edges = [w0] + [x for iv in busy for x in iv] + [w1]
         gaps_all += [(edges[i], edges[i + 1])
@@ -163,6 +220,8 @@ def reduce_profile(pd, devices: int = 1) -> dict:
         "window_s": (w1 - w0) * 1e-9,
         "busy_s": busy_total * 1e-9 / n_dev,
         "op_s": dict(op_s),
+        "instr_s": {k: v / n_dev for k, v in instr_s.items()},
+        "overlap_s": overlap / n_dev,
         "device_ops": [[k, v] for k, v in label_s.most_common(10)],
         "idle_gaps": [[k, v / n_dev] for k, v in idle.most_common(10)],
         "n_device_events": n_events,
